@@ -55,4 +55,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import use_compile_cache
+    use_compile_cache()
     main()

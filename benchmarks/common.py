@@ -29,18 +29,20 @@ def time_fn(fn, *args, warmup: int = 2, iters: int = 5) -> float:
 
 def run_subprocess_bench(module: str, n_devices: int = 8, timeout: int = 1800,
                          extra_env: dict | None = None) -> str:
-    """Run ``python -m benchmarks.<module>`` with N virtual host devices.
+    """Run ``python -m benchmarks.<module>`` on N virtual CPU devices.
 
-    Benchmarks needing multiple devices run in a subprocess so the main bench
-    process (and its CSV) keeps seeing the real single device."""
+    The child is pinned to ``JAX_PLATFORMS=cpu``: a parent that has touched
+    JAX holds the accelerator, so a child asking for it would fail or hang.
+    A failed child raises ``RuntimeError`` with the end of its stderr."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
     env.update(extra_env or {})
     r = subprocess.run([sys.executable, "-m", f"benchmarks.{module}"],
                        env=env, capture_output=True, text=True,
                        timeout=timeout, cwd=ROOT)
     if r.returncode != 0:
-        print(f"# {module} FAILED:\n{r.stderr[-2000:]}", file=sys.stderr)
-        return ""
+        raise RuntimeError(f"{module} failed (exit {r.returncode}):\n"
+                           f"{r.stderr[-2000:]}")
     return r.stdout

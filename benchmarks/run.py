@@ -1,8 +1,9 @@
 """Benchmark driver — one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV.  Multi-device benches run in
-subprocesses with virtual host devices so this process keeps the real single
-device (see benchmarks.common.run_subprocess_bench).
+subprocesses on virtual CPU devices, pinned to the CPU so this process keeps
+the accelerator (see benchmarks.common.run_subprocess_bench); their rows are
+host-CPU timings.  Exits non-zero if any bench failed.
 
   bench_tpch            Fig 10  workload performance + Table 4 counts
   bench_baseline        §6.7    engine vs CPU (NumPy) baseline
@@ -18,6 +19,8 @@ from __future__ import annotations
 
 import sys
 
+from repro import use_compile_cache
+
 from . import (bench_baseline, bench_kernels, bench_projection,
                bench_roofline, bench_tpch)
 from .common import run_subprocess_bench
@@ -31,8 +34,10 @@ LOCAL = [("bench_tpch", bench_tpch), ("bench_baseline", bench_baseline),
 
 
 def main() -> None:
+    use_compile_cache()
     print("name,us_per_call,derived")
     only = set(sys.argv[1:])
+    failed = []
     for name, mod in LOCAL:
         if only and name not in only:
             continue
@@ -41,10 +46,15 @@ def main() -> None:
     for name in SUBPROCESS:
         if only and name not in only:
             continue
-        print(f"# {name} (8 virtual devices)", flush=True)
-        out = run_subprocess_bench(name)
-        sys.stdout.write(out)
+        print(f"# {name} (8 virtual CPU devices, host timings)", flush=True)
+        try:
+            sys.stdout.write(run_subprocess_bench(name))
+        except RuntimeError as e:
+            print(f"# {e}", file=sys.stderr)
+            failed.append(name)
         sys.stdout.flush()
+    if failed:
+        sys.exit(f"failed benches: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -1,6 +1,7 @@
 """End-to-end distributed analytics driver (the paper's Figure 1b workflow).
 
-Runs the full 22-query TPC-H workload SPMD over 8 (virtual) devices with the
+Runs the full 22-query TPC-H workload SPMD over every device JAX sees (the
+chips of a TPU host, or 8 virtual CPU devices without one) with the
 fault-tolerant runner: host-partitioned load (§4.3), capacity-bounded
 collective exchanges, re-execution on overflow, per-query exchange stats.
 
@@ -15,6 +16,7 @@ import time
 
 import jax
 
+from repro import use_compile_cache
 from repro.data import tpch
 from repro.distributed.fault import QueryRunner
 from repro.queries import QUERIES
@@ -27,6 +29,7 @@ def main():
     ap.add_argument("--queries", type=str, default="")
     args = ap.parse_args()
 
+    use_compile_cache()
     n = len(jax.devices())
     mesh = make_mesh((n,), ("data",))
     print(f"devices={n}  scale factor={args.sf}")

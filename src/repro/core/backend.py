@@ -505,13 +505,7 @@ class LocalContext(_BaseContext):
         self._chaos_point("finalize")
         if not replicated:
             self._count("gather", self._wire_entry("gather", t, wire))
-        if sort_keys:
-            t = rel.sort_by(t, sort_keys)   # sorted output is compact
-        else:
-            t = rel.ensure_compact(t)       # finalize is a contiguity boundary
-        if limit is not None:
-            t = rel.limit(t, limit)
-        return t
+        return _order_limit(t, sort_keys, limit)
 
     def nrows(self, t):
         return t.count
@@ -670,18 +664,10 @@ class DistContext(LocalContext):
         tamper = self._chaos_point(
             "finalize", tamperable=self.packed and not replicated)
         if replicated:
-            if sort_keys:
-                t = rel.sort_by(t, sort_keys)
-            else:
-                t = rel.ensure_compact(t)
-            if limit is not None:
-                t = rel.limit(t, limit)
-            return t
+            return _order_limit(t, sort_keys, limit)
         self._count("gather")
-        if sort_keys:
-            t = rel.sort_by(t, sort_keys)
-        if limit is not None:
-            t = rel.limit(t, limit)   # local top-k before the gather
+        if sort_keys or limit is not None:
+            t = _order_limit(t, sort_keys, limit)   # local top-k first
         t, ov, cr, stats = ex.broadcast_table(t, self.axis, self.N,
                                               packed=self.packed, wire=wire,
                                               narrow=self.wire_narrow,
@@ -689,13 +675,15 @@ class DistContext(LocalContext):
         self.overflow = self.overflow | ov
         self.corrupt = self.corrupt | cr
         self.stats.log.append(dataclasses.replace(stats, kind="gather"))
-        if sort_keys:
-            t = rel.sort_by(t, sort_keys)
-        else:
-            t = rel.ensure_compact(t)
-        if limit is not None:
-            t = rel.limit(t, limit)
-        return t
+        return _order_limit(t, sort_keys, limit)
+
+
+def _order_limit(t: Table, sort_keys, limit: int | None) -> Table:
+    """Finalize's ORDER BY / LIMIT on a JAX table; the output is compact."""
+    if limit is not None:
+        return rel.sort_limit(t, sort_keys, limit) if sort_keys else \
+            rel.limit(t, limit)
+    return rel.sort_by(t, sort_keys) if sort_keys else rel.ensure_compact(t)
 
 
 # ===========================================================================
@@ -819,24 +807,25 @@ def partition_database(db: Database, n: int,
     return out, caps
 
 
-def run_distributed(query_fn, db: Database, mesh: Mesh, axis: str = "data",
-                    capacity_factor: float = 2.0, packed_exchange: bool = True,
-                    partition_keys: dict | None = None,
-                    join_method: str = "sorted",
-                    use_kernel: bool | None = None,
-                    wire_format: str | None = None,
-                    chaos=None,
-                    ) -> tuple[dict, PlanStats, Any]:
-    """Run a query SPMD over ``mesh[axis]``; returns (result, stats, overflow).
+def place_partitions(sharded: dict[str, dict], mesh: Mesh,
+                     axis: str = "data") -> dict[str, dict]:
+    """Host partitions (``partition_database``) -> device arrays, each
+    device's slice copied straight to that device along ``mesh[axis]``."""
+    spec = NamedSharding(mesh, P(axis))
+    return {name: {k: jax.device_put(v, spec) for k, v in cols.items()}
+            for name, cols in sharded.items()}
 
-    One logical process per device, all executing the same tensor program —
-    the paper's MPI model realized as a single shard_map program.  A payload
-    integrity failure (``ctx.corrupt``, set by the wire checksums — possibly
-    via an armed ``chaos`` injector's tamper) raises :class:`CorruptPayload`
-    host-side: corrupted buffers are never decoded into served results.
-    """
+
+def distributed_program(query_fn, db: Database, mesh: Mesh,
+                        axis: str = "data", capacity_factor: float = 2.0,
+                        packed_exchange: bool = True,
+                        join_method: str = "sorted",
+                        use_kernel: bool | None = None,
+                        wire_format: str | None = None, chaos=None):
+    """The jitted SPMD program ``run_distributed`` calls on the
+    ``place_partitions`` inputs, and the dict its trace fills with the plan
+    statistics (``"stats"``)."""
     n = mesh.shape[axis]
-    sharded, caps = partition_database(db, n, partition_keys)
     holder = {}
 
     def spmd(tree):
@@ -857,11 +846,33 @@ def run_distributed(query_fn, db: Database, mesh: Mesh, axis: str = "data",
         return (Table(dict(out.columns), out.count.reshape(1)),
                 ctx.overflow.reshape(1), ctx.corrupt.reshape(1))
 
-    inp = {name: {k: jnp.asarray(v) for k, v in cols.items()}
-           for name, cols in sharded.items()}
-    fn = jax.jit(compat.shard_map(spmd, mesh=mesh, in_specs=P(axis),
-                                  out_specs=P(axis)))
-    out, overflow, corrupt = fn(inp)
+    return jax.jit(compat.shard_map(spmd, mesh=mesh, in_specs=P(axis),
+                                    out_specs=P(axis))), holder
+
+
+def run_distributed(query_fn, db: Database, mesh: Mesh, axis: str = "data",
+                    capacity_factor: float = 2.0, packed_exchange: bool = True,
+                    partition_keys: dict | None = None,
+                    join_method: str = "sorted",
+                    use_kernel: bool | None = None,
+                    wire_format: str | None = None,
+                    chaos=None,
+                    ) -> tuple[dict, PlanStats, Any]:
+    """Run a query SPMD over ``mesh[axis]``; returns (result, stats, overflow).
+
+    One logical process per device, all executing the same tensor program —
+    the paper's MPI model realized as a single shard_map program.  A payload
+    integrity failure (``ctx.corrupt``, set by the wire checksums — possibly
+    via an armed ``chaos`` injector's tamper) raises :class:`CorruptPayload`
+    host-side: corrupted buffers are never decoded into served results.
+    """
+    n = mesh.shape[axis]
+    sharded, _ = partition_database(db, n, partition_keys)
+    fn, holder = distributed_program(query_fn, db, mesh, axis,
+                                     capacity_factor, packed_exchange,
+                                     join_method, use_kernel, wire_format,
+                                     chaos)
+    out, overflow, corrupt = fn(place_partitions(sharded, mesh, axis))
     if bool(np.any(np.asarray(corrupt))):
         raise wi.CorruptPayload(
             "distributed run: payload integrity check failed")

@@ -1,10 +1,8 @@
-"""Version-compat shims for jax APIs that moved between releases.
+"""Mesh and shard_map construction with the engine's fixed settings.
 
-The repo targets both current jax (``jax.shard_map``, ``jax.make_mesh`` with
-``axis_types=jax.sharding.AxisType``) and the 0.4.x line (no ``AxisType``,
-``shard_map`` under ``jax.experimental``, ``check_rep`` instead of
-``check_vma``).  Everything mesh/shard_map-shaped goes through here so no
-call site hard-codes one API generation.
+Every mesh axis is ``Auto`` (the compiler places what the program leaves
+unsharded), and shard_map skips its per-shard replication check: the query
+programs return per-device tables whose replication the engine tracks itself.
 """
 from __future__ import annotations
 
@@ -13,32 +11,15 @@ import jax
 __all__ = ["make_mesh", "shard_map"]
 
 
-def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with explicit Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(axis_shapes, axis_names,
-                                 axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shapes, axis_names)
-    # pre-0.4.35: no jax.make_mesh at all
-    from jax.experimental import mesh_utils
-    devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def shard_map(f, mesh, in_specs, out_specs):
     """``jax.shard_map`` with per-shard replication checking disabled."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

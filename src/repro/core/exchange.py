@@ -251,14 +251,9 @@ def shuffle(t: Table, key: jax.Array, axis_name: str, num_partitions: int,
         cols = {}
         words = 0
         for name in t.names:
-            v = t[name]
-            if v.dtype == jnp.bool_:
-                v = v.astype(jnp.int32)
-            part = jax.lax.bitcast_convert_type(v, jnp.int32)
-            if part.ndim == 1:
-                part = part[:, None]
+            part = wi.to_words(t[name])
             got = _exchange(part)
-            cols[name] = _unbitcast(got, t[name].dtype)
+            cols[name] = wi.from_words(got, t[name].dtype)
             words += part.shape[1]
         n_coll = len(t.names) + 1              # + metadata round
         msg_rows = cap_per_dest
@@ -284,14 +279,6 @@ def shuffle(t: Table, key: jax.Array, axis_name: str, num_partitions: int,
         wire=wire_tag,
     )
     return out, overflow, corrupt, recv_counts, stats
-
-
-def _unbitcast(part: jax.Array, dt) -> jax.Array:
-    if dt == jnp.bool_:
-        return part[:, 0].astype(jnp.bool_)
-    if part.shape[1] == 1:
-        return jax.lax.bitcast_convert_type(part[:, 0], dt)
-    return jax.lax.bitcast_convert_type(part, dt)
 
 
 # ---------------------------------------------------------------------------
@@ -343,14 +330,9 @@ def broadcast_table(t: Table, axis_name: str, num_partitions: int,
         counts = jax.lax.all_gather(t.count.reshape(1), axis_name, tiled=True)
         cols, words = {}, 0
         for name in t.names:
-            v = t[name]
-            if v.dtype == jnp.bool_:
-                v = v.astype(jnp.int32)
-            part = jax.lax.bitcast_convert_type(v, jnp.int32)
-            if part.ndim == 1:
-                part = part[:, None]
+            part = wi.to_words(t[name])
             got = jax.lax.all_gather(part, axis_name, tiled=True)
-            cols[name] = _unbitcast(got, t[name].dtype)
+            cols[name] = wi.from_words(got, t[name].dtype)
             words += part.shape[1]
         n_coll, msg_rows = len(t.names) + 1, cap
         row_wire = words * 4
@@ -414,16 +396,18 @@ def broadcast_table_p2p(t: Table, axis_name: str, num_partitions: int,
 
 def partial_to_global(partials: dict[str, jax.Array], ops: dict[str, str],
                       axis_name: str) -> dict[str, jax.Array]:
-    """ncclAllReduce equivalent for final scalar aggregation."""
+    """ncclAllReduce equivalent for final scalar aggregation.
+
+    min/max gather the partials and reduce them locally: TPU lowers only a
+    sum all-reduce of float64, and the gather is exact for every dtype."""
     out = {}
     for k, v in partials.items():
         op = ops[k]
         if op in ("sum", "count"):
             out[k] = jax.lax.psum(v, axis_name)
-        elif op == "min":
-            out[k] = jax.lax.pmin(v, axis_name)
-        elif op == "max":
-            out[k] = jax.lax.pmax(v, axis_name)
+        elif op in ("min", "max"):
+            got = jax.lax.all_gather(v, axis_name)
+            out[k] = (jnp.min if op == "min" else jnp.max)(got, axis=0)
         else:
             raise ValueError(op)
     return out

@@ -67,6 +67,10 @@ from repro.kernels.segsum import ops as _ss_ops
 # 64 lane-tiles) is the practical MXU ceiling; larger domains fall back to
 # the single-sort path.
 DIRECT_AGG_BITS_MAX = 13
+# Largest table an ORDER BY ranks pairwise instead of sorting, on TPU only:
+# XLA:TPU took 475 s to compile a stable (float64, int64) sort of 33,280
+# rows for v5e, while ranking 2^18 rows pairwise is ~7*10^10 compares.
+PAIRWISE_ORDER_MAX = 1 << 18
 # Largest claimed group bound the hash-compaction path will take on: the
 # dictionary is sized groups_hint * capacity_factor (<= 8192 slots at the
 # default factor), keeping both the dictionary planes and the segsum one-hot
@@ -114,6 +118,7 @@ __all__ = [
     "left_join",
     "group_aggregate",
     "sort_by",
+    "sort_limit",
     "limit",
     "static_shrink",
     "hash_partition_ids",
@@ -647,13 +652,10 @@ def _dtype_min(dt):
 # ordering
 # ---------------------------------------------------------------------------
 
-def sort_by(t: Table, keys: Sequence[tuple[str, bool]]) -> Table:
-    """ORDER BY; keys = [(column, ascending)], first key most significant.
-
-    ONE stable multi-operand ``lax.sort`` (lexicographic over all key columns
-    at once) instead of the seed's one argsort pass per key; invalid rows sink
-    to the back via sentinels in every key operand, so the output is compact.
-    """
+def _order_operands(t: Table, keys: Sequence[tuple[str, bool]]
+                    ) -> list[jax.Array]:
+    """ORDER BY keys as ascending sort operands; invalid rows get sentinels
+    in every operand, so they sort behind every valid row."""
     valid = t.valid_mask()
     operands = []
     for col, asc in keys:
@@ -664,8 +666,92 @@ def sort_by(t: Table, keys: Sequence[tuple[str, bool]]) -> Table:
             k = k.astype(_I64)
             k = jnp.where(valid, k if asc else -k, KEY_SENTINEL)
         operands.append(k)
-    iota = jnp.arange(t.capacity, dtype=jnp.int32)
-    res = jax.lax.sort(tuple(operands) + (iota,), num_keys=len(operands),
-                       is_stable=True)
-    order = res[-1]
+    return operands
+
+
+def sort_by(t: Table, keys: Sequence[tuple[str, bool]]) -> Table:
+    """ORDER BY; keys = [(column, ascending)], first key most significant.
+
+    A stable lexicographic order over all key columns at once; invalid rows
+    sink to the back via sentinels in every key operand, so the output is
+    compact.  ONE stable multi-operand ``lax.sort``, except on TPU up to
+    ``PAIRWISE_ORDER_MAX`` rows, where each row's place is counted pairwise
+    (``_pairwise_order``) and nothing is sorted.
+    """
+    operands = _order_operands(t, keys)
+    if t.capacity <= PAIRWISE_ORDER_MAX:
+        order = jax.lax.platform_dependent(
+            *operands, tpu=_pairwise_order, default=_sort_order)
+    else:
+        order = _sort_order(*operands)
     return Table({k: v[order] for k, v in t.columns.items()}, t.count)
+
+
+def _sort_order(*operands: jax.Array) -> jax.Array:
+    """Row positions in the stable lexicographic order of ``operands``."""
+    iota = jnp.arange(operands[0].shape[0], dtype=jnp.int32)
+    return jax.lax.sort(operands + (iota,), num_keys=len(operands),
+                        is_stable=True)[-1]
+
+
+def _pairwise_order(*operands: jax.Array) -> jax.Array:
+    """``_sort_order`` without a sort: each row goes to its stable rank."""
+    iota = jnp.arange(operands[0].shape[0], dtype=jnp.int32)
+    return jnp.zeros_like(iota).at[_stable_ranks(operands)].set(iota)
+
+
+def _stable_ranks(operands: Sequence[jax.Array]) -> jax.Array:
+    """Each row's position in the stable lexicographic order of the key
+    ``operands``: the rows that compare smaller, or equal and earlier.
+    Blocks of rows against all rows, so a tile holds ~2^22 compares."""
+    cap = operands[0].shape[0]
+    blk = max(1, min(cap, (1 << 22) // cap))
+    pos = jnp.arange(cap, dtype=jnp.int32)
+
+    def block_ranks(start):
+        rows = jnp.minimum(start + jnp.arange(blk, dtype=jnp.int32), cap - 1)
+        before = pos[None, :] < rows[:, None]          # equal and earlier
+        for k in reversed(operands):                   # least significant 1st
+            kr, ks = k[rows][:, None], k[None, :]
+            before = (ks < kr) | ((ks == kr) & before)
+        return jnp.sum(before, axis=1, dtype=jnp.int32)
+
+    starts = jnp.arange(0, cap, blk, dtype=jnp.int32)
+    return jax.lax.map(block_ranks, starts).reshape(-1)[:cap]
+
+
+def sort_limit(t: Table, keys: Sequence[tuple[str, bool]], n: int) -> Table:
+    """ORDER BY ... LIMIT n: ``limit(sort_by(t, keys), n)``, row for row.
+
+    On TPU, below the capacity, it sorts nothing: n rounds each pick the
+    smallest row not yet taken, earliest position first
+    (``_smallest_rows``).  XLA:TPU spends minutes compiling a multi-operand
+    64-bit sort of 10^5+ rows; a LIMIT needs n.
+    """
+    if n >= t.capacity:
+        return limit(sort_by(t, keys), n)
+    order = jax.lax.platform_dependent(
+        *_order_operands(t, keys),
+        tpu=lambda *ops: _smallest_rows(ops, n),
+        default=lambda *ops: _sort_order(*ops)[:n])
+    return Table({k: v[order] for k, v in t.columns.items()},
+                 jnp.minimum(t.count, n).astype(jnp.int32))
+
+
+def _smallest_rows(operands: Sequence[jax.Array], n: int) -> jax.Array:
+    """Positions of the ``n`` lexicographically smallest rows of the key
+    ``operands``, in order, ties by position (a stable sort's first n)."""
+    cap = operands[0].shape[0]
+
+    def pick(i, carry):
+        taken, order = carry
+        cand = ~taken
+        for k in operands:
+            m = jnp.min(jnp.where(cand, k, _dtype_max(k.dtype)))
+            cand = cand & (k == m)
+        row = jnp.argmax(cand).astype(jnp.int32)    # first candidate
+        return taken.at[row].set(True), order.at[i].set(row)
+
+    _, order = jax.lax.fori_loop(
+        0, n, pick, (jnp.zeros((cap,), bool), jnp.zeros((n,), jnp.int32)))
+    return order

@@ -273,6 +273,50 @@ def _as_u32(x: jax.Array) -> jax.Array:
     return jax.lax.bitcast_convert_type(x, jnp.uint32)
 
 
+def _f64_pair(v: jax.Array) -> jax.Array:
+    # XLA:TPU gives float64 no IEEE bit pattern (it cannot bitcast it), so
+    # it ships as hi = float32(v), lo = float32(v - hi): v == hi + lo
+    # wherever the device holds v as such a pair, within float32's range
+    hi = v.astype(jnp.float32)
+    lo = jnp.where(jnp.isfinite(hi), v - hi.astype(v.dtype), 0)
+    return jax.lax.bitcast_convert_type(
+        jnp.stack([hi, lo.astype(jnp.float32)], axis=1), jnp.int32)
+
+
+def _pair_f64(w: jax.Array) -> jax.Array:
+    f = jax.lax.bitcast_convert_type(w, jnp.float32)
+    return f[:, 0].astype(jnp.float64) + f[:, 1].astype(jnp.float64)
+
+
+def to_words(v: jax.Array) -> jax.Array:
+    """A column as (rows, 1 or 2) int32 wire words.
+
+    Bool and narrower ints widen to one word; 4- and 8-byte values ship
+    their bits, except float64 on TPU, which ships as a float32 pair
+    (``_f64_pair``)."""
+    if v.dtype == jnp.bool_ or v.dtype.itemsize < 4:
+        return v.astype(jnp.int32)[:, None]
+    if v.dtype == jnp.float64:
+        return jax.lax.platform_dependent(
+            v, tpu=_f64_pair,
+            default=lambda v: jax.lax.bitcast_convert_type(v, jnp.int32))
+    w = jax.lax.bitcast_convert_type(v, jnp.int32)
+    return w[:, None] if w.ndim == 1 else w
+
+
+def from_words(w: jax.Array, dt) -> jax.Array:
+    """Inverse of :func:`to_words`: (rows, 1 or 2) int32 words -> column."""
+    dt = np.dtype(dt)
+    if dt == np.bool_ or dt.itemsize < 4:
+        return w[:, 0].astype(dt)
+    if dt == np.float64:
+        return jax.lax.platform_dependent(
+            w, tpu=_pair_f64,
+            default=lambda w: jax.lax.bitcast_convert_type(w, jnp.float64))
+    return jax.lax.bitcast_convert_type(w[:, 0] if dt.itemsize == 4 else w,
+                                        dt)
+
+
 def pack_table(t, fmt: WireFormat) -> tuple[jax.Array, jax.Array]:
     """Table -> ((capacity, fmt.words) int32 buffer, overflow flag).
 
@@ -305,13 +349,9 @@ def pack_table(t, fmt: WireFormat) -> tuple[jax.Array, jax.Array]:
                 continue                        # reconstructed from lo
             _or(c.word, u << c.shift if c.shift else u)
         elif c.mode == "word":
-            if dt == np.bool_ or dt.itemsize < 4:
-                x = v.astype(jnp.int32)         # widen (legacy bool behavior)
-            else:
-                x = jax.lax.bitcast_convert_type(v, jnp.int32)
-            _or(c.word, _as_u32(x))
+            _or(c.word, _as_u32(to_words(v)[:, 0]))
         elif c.mode == "split":
-            x = jax.lax.bitcast_convert_type(v, jnp.int32)   # (cap, 2)
+            x = to_words(v)                                  # (cap, 2)
             _or(c.word, _as_u32(x[:, 0]))
             _or(c.word + 1, _as_u32(x[:, 1]))
         else:
@@ -343,15 +383,9 @@ def unpack_table(buf: jax.Array, fmt: WireFormat) -> dict[str, jax.Array]:
                 out[c.name] = (u.astype(jnp.int64) + c.lo).astype(dt)
         elif c.mode == "u32":
             out[c.name] = (ub[:, c.word].astype(jnp.int64) + c.lo).astype(dt)
-        elif c.mode == "word":
-            w = buf[:, c.word]
-            if dt == np.bool_ or dt.itemsize < 4:
-                out[c.name] = w.astype(dt)
-            else:
-                out[c.name] = jax.lax.bitcast_convert_type(w, dt)
-        elif c.mode == "split":
-            out[c.name] = jax.lax.bitcast_convert_type(
-                buf[:, c.word:c.word + 2], dt)
+        elif c.mode in ("word", "split"):
+            k = 2 if c.mode == "split" else 1
+            out[c.name] = from_words(buf[:, c.word:c.word + k], dt)
         else:
             raise ValueError(f"unknown wire mode {c.mode!r}")
     return out

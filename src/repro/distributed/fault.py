@@ -20,9 +20,10 @@ so the runner reacts to what actually went wrong instead of retrying blindly:
   CORRUPT        a packed payload failed its wire integrity checksum
                  (:class:`repro.core.wire.CorruptPayload`): re-run on the
                  conservative wide format — never serve the bad buffer.
-  DETERMINISTIC  a plan-author bug (TypeError, ValueError, assertion …):
-                 raised immediately on attempt 1 — re-execution cannot fix
-                 code.
+  DETERMINISTIC  a plan-author bug (TypeError, ValueError, assertion …), or
+                 a program the device refuses (a kernel the compiler
+                 rejects, a program that exhausts device memory): raised
+                 immediately on attempt 1 — re-execution cannot fix code.
   DEVICE_LOST    one or more mesh participants are permanently dead
                  (:class:`repro.distributed.chaos.DeviceLost`): retrying on
                  the same topology can only fail again.  The runner shrinks
@@ -53,6 +54,7 @@ from __future__ import annotations
 import dataclasses
 import time
 
+import jax
 import numpy as np
 from jax.sharding import Mesh
 
@@ -75,21 +77,31 @@ __all__ = [
 _DETERMINISTIC_EXC = (TypeError, ValueError, KeyError, IndexError,
                       AttributeError, AssertionError, NameError,
                       ZeroDivisionError)
+# JAX runtime errors that re-running the same program cannot fix: a Pallas
+# kernel the Mosaic compiler refuses, a program that does not fit device
+# memory.  Other statuses (INTERNAL included) may be device or runtime
+# faults, and stay TRANSIENT.
+_DETERMINISTIC_XLA = ("Mosaic failed to compile", "RESOURCE_EXHAUSTED:")
 
 
 def classify_failure(exc: BaseException) -> FailureKind:
     """Map a raised exception onto the failure taxonomy.
 
-    ``CorruptPayload`` -> CORRUPT; plan-author bug types -> DETERMINISTIC;
-    everything else (``TransientFault``, OSError, timeouts, the unknown) is
-    treated as a TRANSIENT environment fault and retried — the conservative
-    default, bounded by ``RetryPolicy.max_attempts``.
+    ``CorruptPayload`` -> CORRUPT; plan-author bug types and JAX runtime
+    errors whose status says the program itself is at fault (compile
+    failure, out of device memory) -> DETERMINISTIC; everything else
+    (``TransientFault``, OSError, timeouts, the unknown) is treated as a
+    TRANSIENT environment fault and retried — the conservative default,
+    bounded by ``RetryPolicy.max_attempts``.
     """
     if isinstance(exc, DeviceLost):
         return FailureKind.DEVICE_LOST
     if isinstance(exc, CorruptPayload):
         return FailureKind.CORRUPT
     if isinstance(exc, _DETERMINISTIC_EXC):
+        return FailureKind.DETERMINISTIC
+    if isinstance(exc, jax.errors.JaxRuntimeError) and \
+            any(m in str(exc) for m in _DETERMINISTIC_XLA):
         return FailureKind.DETERMINISTIC
     return FailureKind.TRANSIENT
 

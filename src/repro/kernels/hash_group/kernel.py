@@ -8,12 +8,12 @@ built by atomics; the TPU adaptation here is a **write-once open-addressing
 dictionary built in VMEM across a sequential row-block grid** — the same
 trick ``radix_hist.counting_rank`` uses for its running totals:
 
-  * the dictionary is three ``(cap, 1)`` VMEM scratch planes — two int32 key
+  * the dictionary is three ``(1, cap)`` VMEM scratch rows — two int32 key
     planes holding the full 64-bit key (the ``hash_probe`` two-plane scheme,
     probed with the SAME ``bucket_of`` mix so both kernels hash identically)
     plus an occupancy plane — carried across grid steps;
   * each block's rows probe in lockstep rounds (linear probing from
-    ``bucket_of(key)``): a round gathers the candidate slot, resolves rows
+    ``bucket_of(key)``): a round reads the candidate slot, resolves rows
     whose key already sits there, and elects ONE writer per empty slot by a
     one-hot minimum over row indices — no atomics, no scatter, and a slot
     transitions empty -> occupied exactly once (write-once), so a resolved
@@ -34,10 +34,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import I32_ZERO as _ZERO
 from repro.kernels.hash_probe.kernel import bucket_of
+
+
+def _pick(onehot: jax.Array, plane: jax.Array, axis: int) -> jax.Array:
+    """The one element of ``plane`` that ``onehot`` selects along ``axis``
+    (a select + exact int32 sum — Mosaic has no gather from a VMEM table)."""
+    return jnp.sum(jnp.where(onehot, plane, _ZERO), axis=axis, keepdims=True,
+                   dtype=jnp.int32)
 
 
 def _insert_kernel(plo_ref, phi_ref, pv_ref, slot_ref, dlo_ref, dhi_ref,
@@ -51,45 +60,45 @@ def _insert_kernel(plo_ref, phi_ref, pv_ref, slot_ref, dlo_ref, dhi_ref,
         thi[...] = jnp.zeros_like(thi)
         tocc[...] = jnp.zeros_like(tocc)
 
-    lo = plo_ref[...][:, 0]                                   # (blk,)
-    hi = phi_ref[...][:, 0]
-    valid = pv_ref[...][:, 0] != 0
+    lo = plo_ref[...]                                         # (blk, 1)
+    hi = phi_ref[...]
     b = bucket_of(lo, hi, cap)
     rows = jax.lax.broadcasted_iota(jnp.int32, (blk, 1), 0)   # (blk, 1)
     iota_c = jax.lax.broadcasted_iota(jnp.int32, (blk, cap), 1)
-    big = jnp.int32(blk)
+    big = np.int32(blk)
+    one = np.int32(1)
 
-    def body(r, carry):
-        unres, out = carry
-        s = jax.lax.rem(b + r.astype(jnp.int32), jnp.int32(cap))  # linear probe
-        cl = tlo[...][s][:, 0]                                # (blk,) gathers
-        ch = thi[...][s][:, 0]
-        co = tocc[...][s][:, 0]
-        hit = unres & (co == 1) & (cl == lo) & (ch == hi)
-        out = jnp.where(hit, s, out)
-        unres = unres & ~hit
+    def lookup(s_hot, unres, out, s):
+        # resolve rows whose key already sits in their candidate slot
+        hit = (unres == one) & (_pick(s_hot, tocc[...], 1) == one) & \
+            (_pick(s_hot, tlo[...], 1) == lo) & \
+            (_pick(s_hot, thi[...], 1) == hi)
+        return jnp.where(hit, _ZERO, unres), jnp.where(hit, s, out)
+
+    def body(carry):
+        r, unres, out = carry                                 # (blk, 1) i32
+        s = jax.lax.rem(b + r, np.int32(cap))                 # linear probe
+        s_hot = s == iota_c                                   # (blk, cap)
+        unres, out = lookup(s_hot, unres, out, s)
         # elect ONE writer per still-empty slot: min row index attempting
-        att = unres & (co == 0)
-        m = att[:, None] & (s[:, None] == iota_c)             # (blk, cap)
-        win = jnp.min(jnp.where(m, rows, big), axis=0)        # (cap,)
-        has = (win < big)[:, None]                            # (cap, 1)
-        widx = jnp.minimum(win, blk - 1)
-        tlo[...] = jnp.where(has, lo[:, None][widx], tlo[...])
-        thi[...] = jnp.where(has, hi[:, None][widx], thi[...])
-        tocc[...] = jnp.where(has, jnp.int32(1), tocc[...])
-        # losers see the winner's key on the re-gather and probe on
-        cl2 = tlo[...][s][:, 0]
-        ch2 = thi[...][s][:, 0]
-        co2 = tocc[...][s][:, 0]
-        hit2 = unres & (co2 == 1) & (cl2 == lo) & (ch2 == hi)
-        out = jnp.where(hit2, s, out)
-        unres = unres & ~hit2
-        return unres, out
+        att = (unres == one) & (_pick(s_hot, tocc[...], 1) == _ZERO)
+        m = att & s_hot                                       # (blk, cap)
+        win = jnp.min(jnp.where(m, rows, big), axis=0,
+                      keepdims=True)                          # (1, cap)
+        has = win < big
+        won = m & (rows == win)                   # the winner's one-hot row
+        tlo[...] = jnp.where(has, _pick(won, lo, 0), tlo[...])
+        thi[...] = jnp.where(has, _pick(won, hi, 0), thi[...])
+        tocc[...] = jnp.where(has, one, tocc[...])
+        # losers see the winner's key on the re-lookup and probe on
+        return (r + one,) + lookup(s_hot, unres, out, s)
 
-    unres0 = valid
-    out0 = jnp.full((blk,), -1, jnp.int32)
-    _, out = jax.lax.fori_loop(0, rounds, body, (unres0, out0))
-    slot_ref[...] = out[:, None]
+    unres0 = (pv_ref[...] != _ZERO).astype(jnp.int32)
+    out0 = jnp.full((blk, 1), np.int32(-1))
+    # an int32 round counter: fori_loop would carry an int64 one under x64
+    _, _, out = jax.lax.while_loop(lambda c: c[0] < np.int32(rounds), body,
+                                   (_ZERO, unres0, out0))
+    slot_ref[...] = out
     # the dictionary outputs are pinned to block 0: the last grid step's write
     # is the final table (cheap — cap is small)
     dlo_ref[...] = tlo[...]
@@ -106,37 +115,23 @@ def hash_insert_pallas(plo: jax.Array, phi: jax.Array, pvalid: jax.Array,
     (int32, ``-1`` = invalid or unresolved after ``rounds`` probes) plus the
     final key planes and int32 occupancy of the dictionary.
 
-    VMEM working set: 3 ``(cap, 1)`` scratch planes resident across the
+    VMEM working set: 3 ``(1, cap)`` scratch rows resident across the
     sequential grid + the ``(blk, cap)`` election tile per round — callers
     bound ``blk * cap`` (``ops.build_group_dict`` does).
     """
     n = plo.shape[0]
     assert n % blk == 0, (n, blk)
     grid = (n // blk,)
-    return pl.pallas_call(
+    row = pl.BlockSpec((blk, 1), lambda i: (i, _ZERO))
+    table = pl.BlockSpec((1, cap), lambda i: (_ZERO, _ZERO))  # resident
+    slot, dlo, dhi, docc = pl.pallas_call(
         functools.partial(_insert_kernel, blk=blk, cap=cap, rounds=rounds),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),         # resident
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),         # resident
-            pl.BlockSpec((cap, 1), lambda i: (0, 0)),         # resident
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cap, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cap, 1), jnp.int32),
-            jax.ShapeDtypeStruct((cap, 1), jnp.int32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((cap, 1), jnp.int32),
-            pltpu.VMEM((cap, 1), jnp.int32),
-            pltpu.VMEM((cap, 1), jnp.int32),
-        ],
+        in_specs=[row] * 3,
+        out_specs=[row] + [table] * 3,
+        out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.int32)] +
+        [jax.ShapeDtypeStruct((1, cap), jnp.int32)] * 3,
+        scratch_shapes=[pltpu.VMEM((1, cap), jnp.int32)] * 3,
         interpret=interpret,
     )(plo.reshape(n, 1), phi.reshape(n, 1), pvalid.reshape(n, 1))
+    return slot[:, 0], dlo[0], dhi[0], docc[0]

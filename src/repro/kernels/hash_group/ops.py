@@ -29,12 +29,23 @@ from .ref import hash_insert_ref
 # factor <= 0.5 a 32-slot linear-probe cluster is vanishingly rare, and the
 # overflow/escalation path covers the remainder
 _MAX_ROUNDS = 32
-# cap the (blk, cap) election tile the kernel holds in VMEM (int32 words)
-_ELECT_TILE_MAX = 1 << 21
+# cap the (blk, cap) election tile the kernel holds in VMEM (int32 words):
+# a round keeps several such tiles live, and v5e's compiler holds a kernel to
+# 16 MiB of scoped VMEM (2^21 words reached 19.6 MiB at cap 8192)
+_ELECT_TILE_MAX = 1 << 20
 
 
 def default_rounds(cap: int) -> int:
     return min(cap, _MAX_ROUNDS)
+
+
+def insert_block(cap: int) -> int:
+    """Rows per grid step of the insert kernel for a ``cap``-slot
+    dictionary: the (blk, cap) election tile stays within VMEM."""
+    blk = 512
+    while blk > 8 and blk * cap > _ELECT_TILE_MAX:
+        blk //= 2
+    return blk
 
 
 def dict_capacity(groups_hint: int, factor: float = 2.0) -> int:
@@ -67,10 +78,7 @@ def build_group_dict(keys: jax.Array, valid: jax.Array, cap: int,
     n = keys.shape[0]
     if not use_kernel:
         return hash_insert_ref(keys, valid, cap, rounds)
-    blk = 512
-    while blk > 8 and blk * cap > _ELECT_TILE_MAX:
-        blk //= 2
-    blk = min(blk, max(8, (n + 7) // 8 * 8))
+    blk = min(insert_block(cap), max(8, (n + 7) // 8 * 8))
     npad = (n + blk - 1) // blk * blk
     k = jnp.zeros((npad,), jnp.int64).at[:n].set(keys.astype(jnp.int64))
     v = jnp.zeros((npad,), jnp.int32).at[:n].set(valid.astype(jnp.int32))
@@ -78,9 +86,9 @@ def build_group_dict(keys: jax.Array, valid: jax.Array, cap: int,
     slot, dlo, dhi, docc = hash_insert_pallas(lo, hi, v, cap, blk=blk,
                                               rounds=rounds,
                                               interpret=interpret)
-    slot = slot[:n, 0]
-    dict_keys = _merge64(dlo[:, 0], dhi[:, 0])
-    occupied = docc[:, 0] == 1
+    slot = slot[:n]
+    dict_keys = _merge64(dlo, dhi)
+    occupied = docc == 1
     unresolved = jnp.any(valid & (slot < 0))
     return slot, dict_keys, occupied, unresolved
 

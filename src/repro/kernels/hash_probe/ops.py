@@ -11,6 +11,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import auto_interpret
 from repro.kernels.radix_hist.kernel import murmur32
 from .kernel import SENTINEL, bucket_of, hash_probe_pallas, hash_probe64_pallas
 from .ref import hash_probe_ref
@@ -113,7 +114,7 @@ def hash_probe64(probe_keys: jax.Array, bk_lo: jax.Array, bk_hi: jax.Array,
                  interpret: bool | None = None) -> jax.Array:
     """(n,) int64 probe keys vs a 64-bit bucket table -> build row idx or -1."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = auto_interpret()
     n = probe_keys.shape[0]
     blk = min(blk, max(8, (n + 7) // 8 * 8))
     npad = (n + blk - 1) // blk * blk
@@ -128,12 +129,16 @@ def hash_probe64(probe_keys: jax.Array, bk_lo: jax.Array, bk_hi: jax.Array,
 @partial(jax.jit, static_argnames=("blk", "cap", "interpret", "use_kernel"))
 def hash_join_probe(probe_keys: jax.Array, build_keys: jax.Array,
                     build_vals: jax.Array, blk: int = 2048, cap: int = 8,
-                    interpret: bool = True, use_kernel: bool = True):
+                    interpret: bool | None = None, use_kernel: bool = True):
     """End-to-end probe: returns (matched row idx or -1, build overflowed).
 
-    VMEM budget: the (B, C) tables must fit resident — B*C*8 bytes; with the
-    default C=8 and B = 2*next_pow2(m)/C this is ~16 bytes per build row.
+    VMEM budget: the (B, C) tables must fit resident — B*C*16 bytes as
+    bf16 bytes; with the default C=8 and B = 2*next_pow2(m)/C this is ~32
+    bytes per build row.  ``interpret=None`` auto-selects: compiled on TPU,
+    interpret elsewhere.
     """
+    if interpret is None:
+        interpret = auto_interpret()
     if not use_kernel:
         return hash_probe_ref(probe_keys, build_keys, build_vals), jnp.asarray(False)
     m = build_keys.shape[0]

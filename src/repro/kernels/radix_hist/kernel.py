@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import I32_ZERO
+
 
 def murmur32(k: jax.Array) -> jax.Array:
     """murmur3 fmix32 — vector-friendly 32-bit finalizer."""
@@ -71,11 +73,12 @@ def radix_hist_pallas(keys: jax.Array, parts: int, width: int | None = None,
         functools.partial(_kernel, blk=blk, parts=parts, width=width,
                           hashed=hashed),
         grid=grid,
-        in_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, width), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // blk, width), jnp.float32),
+        in_specs=[pl.BlockSpec((blk, 1), lambda i: (i, I32_ZERO))],
+        out_specs=pl.BlockSpec((pl.squeezed, 1, width),
+                               lambda i: (i, I32_ZERO, I32_ZERO)),
+        out_shape=jax.ShapeDtypeStruct((n // blk, 1, width), jnp.float32),
         interpret=interpret,
-    )(keys.reshape(n, 1).astype(jnp.int32))
+    )(keys.reshape(n, 1).astype(jnp.int32))[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -113,7 +116,7 @@ def _rank_kernel(key_ref, slot_ref, hist_ref, run_ref, *, blk: int,
                                dimension_numbers=(((1,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
     rank = run_ref[0:1, :] + excl.astype(jnp.int32)            # (blk, W)
-    sel = jnp.where(pid == iota_w, rank, 0)
+    sel = jnp.where(pid == iota_w, rank, I32_ZERO)
     slot_ref[...] = jnp.sum(sel, axis=1, keepdims=True,
                             dtype=jnp.int32)                   # (blk, 1)
     bh = jnp.sum(onehot, axis=0, keepdims=True)                # (1, W)
@@ -136,12 +139,13 @@ def counting_rank_pallas(keys: jax.Array, parts: int, width: int,
     slot, hist = pl.pallas_call(
         functools.partial(_rank_kernel, blk=blk, width=width, parts=parts),
         grid=grid,
-        in_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((blk, 1), lambda i: (i, 0)),
-                   pl.BlockSpec((1, width), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((blk, 1), lambda i: (i, I32_ZERO))],
+        out_specs=[pl.BlockSpec((blk, 1), lambda i: (i, I32_ZERO)),
+                   pl.BlockSpec((pl.squeezed, 1, width),
+                                lambda i: (i, I32_ZERO, I32_ZERO))],
         out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.int32),
-                   jax.ShapeDtypeStruct((n // blk, width), jnp.float32)],
+                   jax.ShapeDtypeStruct((n // blk, 1, width), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((8, width), jnp.int32)],
         interpret=interpret,
     )(keys.reshape(n, 1).astype(jnp.int32))
-    return slot[:, 0], hist
+    return slot[:, 0], hist[:, 0]

@@ -5,8 +5,9 @@ Dead-slot convention
 Rows the caller wants excluded (table padding, invalid rows, out-of-domain
 keys) are routed to the **dead slot, which is always index ``groups``** — the
 first id beyond the real group range.  The padded group width ``gpad`` is
-``groups + 1`` rounded up to the 128-lane tile, so the dead slot exists for
-every ``groups`` and is never lane-boundary dependent.  (The previous scheme
+``groups + 1`` rounded up to the 128-lane tile (above 1024, to the kernels'
+1024-group block), so the dead slot exists for every ``groups`` and is never
+lane-boundary dependent.  (The previous scheme
 parked padding rows at ``gpad - 1``; at exact lane boundaries —
 ``groups == gpad - 1``, e.g. groups = 127/255 — a caller-side sentinel id
 ``groups`` and the wrapper's dead row could alias real/dead slots depending
@@ -28,6 +29,11 @@ from repro.kernels import auto_interpret
 from .ref import segment_reduce_ref, segment_sum_ref
 
 _LANES = 128
+# VMEM: the kernels sweep the rows once per block of at most _GROUP_BLOCK
+# groups, with (blk, gblk) tiles of at most _TILE_MAX elements (2 MiB of
+# float32), so a 2^13-group domain stays inside v5e's 16 MiB scoped VMEM
+_GROUP_BLOCK = 1024
+_TILE_MAX = 1 << 19
 # one-hot f32 count matmuls are exact while the row count fits the mantissa
 _F32_EXACT_ROWS = 1 << 24
 
@@ -52,15 +58,26 @@ def _pad_rows(gids: jax.Array, groups: int, blk: int) -> tuple[jax.Array, int, i
     return g2, npad, blk
 
 
+def _group_blocks(groups: int, blk: int) -> tuple[int, int, int]:
+    """(padded group width, group block, row block) for ``groups`` real
+    groups plus the dead slot."""
+    gpad = _pad_to(groups + 1, _LANES)
+    if gpad > _GROUP_BLOCK:
+        gpad = _pad_to(gpad, _GROUP_BLOCK)
+    gblk = min(gpad, _GROUP_BLOCK)
+    return gpad, gblk, min(blk, _TILE_MAX // gblk // 8 * 8)
+
+
 def _sum_kernel(gids: jax.Array, values: jax.Array, groups: int, blk: int,
                 interpret: bool) -> jax.Array:
     """values (n, C) float32/float64 -> (groups, C), via the MXU kernel."""
     n, c = values.shape
-    gpad = _pad_to(groups + 1, _LANES)
+    gpad, gblk, blk = _group_blocks(groups, blk)
     cpad = _pad_to(c, _LANES)
     g2, npad, blk = _pad_rows(gids, groups, blk)
     v2 = jnp.zeros((npad, cpad), values.dtype).at[:n, :c].set(values)
-    out = segment_sum_pallas(g2, v2, gpad, blk=blk, interpret=interpret)
+    out = segment_sum_pallas(g2, v2, gpad, blk=blk, gblk=gblk,
+                             interpret=interpret)
     return out[:groups, :c]
 
 
@@ -68,12 +85,12 @@ def _minmax_kernel(gids: jax.Array, values: jax.Array, groups: int, op: str,
                    blk: int, interpret: bool) -> jax.Array:
     """values (n,) float -> (groups,) min/max via the masked-reduce kernel."""
     n = values.shape[0]
-    gpad = _pad_to(groups + 1, _LANES)
+    gpad, gblk, blk = _group_blocks(groups, blk)
     ident = jnp.asarray(jnp.inf if op == "min" else -jnp.inf, values.dtype)
     g2, npad, blk = _pad_rows(gids, groups, blk)
     v2 = jnp.full((npad,), ident, values.dtype).at[:n].set(values)
     out = segment_minmax_pallas(g2, v2, gpad, is_min=(op == "min"),
-                                blk=blk, interpret=interpret)
+                                blk=blk, gblk=gblk, interpret=interpret)
     return out[:groups]
 
 

@@ -217,6 +217,20 @@ class QueryServer:
 
         return jax.jit(run)
 
+    def compiled(self, template: PlanTemplate | int,
+                 infer: bool | None = None) -> jax.stages.Compiled:
+        """The executable ``submit`` runs for ``template`` (exact serving),
+        as compiled for this server's device; compiles it if not yet served.
+        """
+        if isinstance(template, int):
+            template = TEMPLATES[template]
+        if infer is None:
+            infer = planner.planner_default()
+        pvals = {name: jnp.asarray(v, _PDTYPE[template.params[name].dtype])
+                 for name, v in template.bind().values.items()}
+        fn = self._executable(template, infer, self.capacity_factor)
+        return fn.lower(self._tables, pvals).compile()
+
     def submit(self, template: PlanTemplate | int,
                bindings: dict[str, Any] | None = None,
                infer: bool | None = None,

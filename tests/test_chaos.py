@@ -253,10 +253,19 @@ def test_classification_table():
     assert classify_failure(W.CorruptPayload("x")) is FailureKind.CORRUPT
     for exc in (TypeError("t"), ValueError("v"), KeyError("k"),
                 IndexError("i"), AttributeError("a"), AssertionError("s"),
-                NameError("n"), ZeroDivisionError("z")):
+                NameError("n"), ZeroDivisionError("z"),
+                jax.errors.JaxRuntimeError(
+                    "INTERNAL: Mosaic failed to compile TPU kernel: failed "
+                    "to legalize operation 'func.return'"),
+                jax.errors.JaxRuntimeError(
+                    "RESOURCE_EXHAUSTED: Error allocating device buffer: "
+                    "Attempting to allocate 18.00G. That was not possible.")):
         assert classify_failure(exc) is FailureKind.DETERMINISTIC, exc
     for exc in (TransientFault("gone"), OSError("io"), TimeoutError("slow"),
-                RuntimeError("unknown")):
+                RuntimeError("unknown"),
+                jax.errors.JaxRuntimeError("UNAVAILABLE: link down"),
+                jax.errors.JaxRuntimeError(
+                    "INTERNAL: Core halted unexpectedly")):
         assert classify_failure(exc) is FailureKind.TRANSIENT, exc
 
 

@@ -102,6 +102,53 @@ def test_sort_by_single_key_matches_multipass(db):
         np.testing.assert_array_equal(got[c], d[c][order])
 
 
+def _masked(t, seed):
+    keep = jnp.asarray(np.random.default_rng(seed).random(t.capacity) < 0.6)
+    keep = keep & t.valid_mask()
+    return Table(t.columns, keep.sum().astype(jnp.int32), keep)
+
+
+_ORDER_KEYS = [[("k", True)], [("v", False), ("k", True)],
+               [("k", False), ("k2", True), ("v", True)]]
+
+
+def _order_ops(t, keys):
+    return tuple(R._order_operands(t, keys))
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("keys", _ORDER_KEYS)
+def test_sort_by_pairwise_ranks_match_lax_sort(keys, masked):
+    """Ranking rows pairwise (sort_by's TPU path) orders them exactly as the
+    stable lax.sort does, invalid rows and ties included."""
+    t = _random_table(5)
+    t = _masked(t, 6) if masked else t
+    ops = _order_ops(t, keys)
+    np.testing.assert_array_equal(np.asarray(R._pairwise_order(*ops)),
+                                  np.asarray(R._sort_order(*ops)))
+
+
+@pytest.mark.parametrize("n", [1, 7, 100, 256, 300])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("keys", _ORDER_KEYS)
+def test_sort_limit_matches_sort_then_limit(keys, masked, n):
+    """ORDER BY ... LIMIT n picks the same rows in the same order as the
+    full sort followed by LIMIT; sort_limit's TPU path (n rounds of
+    picking the smallest row) picks the stable sort's first n."""
+    t = _random_table(7)
+    t = _masked(t, 8) if masked else t
+    got = R.sort_limit(t, keys, n)
+    want = R.limit(R.sort_by(t, keys), n)
+    assert int(got.count) == int(want.count)
+    for c in ("k", "k2", "v"):
+        np.testing.assert_array_equal(np.asarray(got[c]),
+                                      np.asarray(want[c]))
+    ops = _order_ops(t, keys)
+    m = min(n, t.capacity)
+    np.testing.assert_array_equal(np.asarray(R._smallest_rows(ops, m)),
+                                  np.asarray(R._sort_order(*ops)[:m]))
+
+
 def test_combine_keys_bits_packing():
     a = jnp.asarray([1, 2, 3], dtype=jnp.int64)
     b = jnp.asarray([4, 5, 6], dtype=jnp.int64)

@@ -77,6 +77,18 @@ def test_wide_layout_matches_legacy_packing():
     assert fmt.row_wire_bytes == 24 and fmt.row_logical_bytes == 21
 
 
+def test_f64_words_tpu_pair_roundtrip():
+    """TPU float64 has no IEEE bit pattern, so it ships as its float32 pair;
+    every value that is such a pair (all a TPU holds) round-trips exactly."""
+    rng = np.random.default_rng(0)
+    hi = (rng.normal(size=64) * 1e5).astype(np.float32)
+    lo = (hi * rng.uniform(-2.0 ** -25, 2.0 ** -25, 64)).astype(np.float32)
+    v = np.concatenate([hi.astype(np.float64) + lo, [0.0, np.inf, -np.inf]])
+    words = W._f64_pair(jnp.asarray(v))
+    assert words.shape == (67, 2) and words.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(W._pair_f64(words)), v)
+
+
 def test_narrow_mode_selection_and_lane_sharing():
     dt = {"dict8": np.dtype(np.int32), "date16": np.dtype(np.int64),
           "key32": np.dtype(np.int64), "flag": np.dtype(bool),
