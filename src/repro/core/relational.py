@@ -44,6 +44,10 @@ derives it (and ``groups_hint``) by bound propagation over the logical plan
 (``core/plan.py``) and passes it here — the physical contract of this module
 is unchanged, only the *source* of the widths moved from comments at call
 sites into a compiler pass.
+
+The joins, the group-by, compaction and ordering trace inside their
+``rel.*`` operator scopes (``core/tracing.py``), so a profile charges each
+device operation to the operator that emitted it.
 """
 from __future__ import annotations
 
@@ -55,6 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from .table import Table, KEY_SENTINEL
 # imported at module scope (not lazily inside traced code): the kernel modules
 # materialize constants at import time, which must not happen under a trace
@@ -133,6 +138,7 @@ _HASH_C2 = np.uint64(0xC4CEB9FE1A85EC53)
 # compaction / filtering
 # ---------------------------------------------------------------------------
 
+@tracing.op_scope(tracing.COMPACT)
 def compact(t: Table, keep: jax.Array) -> Table:
     """Move rows where ``keep & valid`` to the front; count = how many.
 
@@ -257,6 +263,7 @@ class BuildIndex:
     bvals: jax.Array | None = None
 
 
+@tracing.op_scope(tracing.JOIN_BUILD)
 def build_index(build: Table, build_key: jax.Array, method: str = "sorted",
                 bucket_cap: int = 16) -> BuildIndex:
     """Index the build side of a unique-key join (one argsort either way)."""
@@ -275,6 +282,7 @@ def build_index(build: Table, build_key: jax.Array, method: str = "sorted",
                       bk_lo=bk_lo, bk_hi=bk_hi, bvals=bv)
 
 
+@tracing.op_scope(tracing.JOIN_PROBE)
 def probe_index(index: BuildIndex, probe_key: jax.Array,
                 probe_valid: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Probe an index.  Returns (matched, build_row_idx); idx arbitrary where
@@ -312,10 +320,11 @@ def join_unique(probe: Table, build: Table, probe_on: jax.Array,
     matched, bidx = _probe(probe_on, probe.valid_mask(), build, build_on,
                            index, method)
     cols = dict(probe.columns)
-    for name in take:
-        if name in cols:
-            raise ValueError(f"join output column collision: {name}")
-        cols[name] = build[name][bidx]
+    with jax.named_scope(tracing.JOIN_TAKE):
+        for name in take:
+            if name in cols:
+                raise ValueError(f"join output column collision: {name}")
+            cols[name] = build[name][bidx]
     return Table(cols, matched.sum().astype(jnp.int32), matched)
 
 
@@ -341,10 +350,12 @@ def left_join(probe: Table, build: Table, probe_on, build_on,
     matched, bidx = _probe(probe_on, probe.valid_mask(), build, build_on,
                            index, method)
     cols = dict(probe.columns)
-    for name in take:
-        gathered = build[name][bidx]
-        cols[name] = jnp.where(matched, gathered,
-                               jnp.asarray(defaults[name], dtype=gathered.dtype))
+    with jax.named_scope(tracing.JOIN_TAKE):
+        for name in take:
+            gathered = build[name][bidx]
+            cols[name] = jnp.where(
+                matched, gathered,
+                jnp.asarray(defaults[name], dtype=gathered.dtype))
     cols["__matched"] = matched
     return Table(cols, probe.count, probe.valid)
 
@@ -365,6 +376,7 @@ def _agg_value(t: Table, values, cap: int) -> jax.Array:
     return values
 
 
+@tracing.op_scope(tracing.GROUP_BY)
 def group_aggregate(t: Table, key_cols: Sequence[str],
                     aggs: Sequence[tuple[str, str, jax.Array | str | None]],
                     key_bits: Sequence[int] | None = None,
@@ -669,6 +681,7 @@ def _order_operands(t: Table, keys: Sequence[tuple[str, bool]]
     return operands
 
 
+@tracing.op_scope(tracing.ORDER)
 def sort_by(t: Table, keys: Sequence[tuple[str, bool]]) -> Table:
     """ORDER BY; keys = [(column, ascending)], first key most significant.
 
@@ -720,6 +733,7 @@ def _stable_ranks(operands: Sequence[jax.Array]) -> jax.Array:
     return jax.lax.map(block_ranks, starts).reshape(-1)[:cap]
 
 
+@tracing.op_scope(tracing.ORDER)
 def sort_limit(t: Table, keys: Sequence[tuple[str, bool]], n: int) -> Table:
     """ORDER BY ... LIMIT n: ``limit(sort_by(t, keys), n)``, row for row.
 

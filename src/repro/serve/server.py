@@ -50,6 +50,7 @@ import numpy as np
 from repro.core import backend as B
 from repro.core import planner
 from repro.core import relational as rel
+from repro.core import tracing
 from repro.core.table import Table, to_numpy
 from repro.core.wire import CorruptPayload
 from .cache import PlanCache
@@ -141,6 +142,8 @@ class QueryServer:
         self.approx_served = 0       # answers served off a sample rung
         self.approx_escalations = 0  # tolerance misses climbed past
         self.approx_refused = 0      # non-estimable shapes served exact
+        self._requests = 0           # submit's sequence number
+        self._phases = tracing.Phases()
         self._tables = B._np_db_to_tables(db)
         # topology state: logical width this server answers on behalf of
         if devices < 1:
@@ -231,6 +234,11 @@ class QueryServer:
         fn = self._executable(template, infer, self.capacity_factor)
         return fn.lower(self._tables, pvals).compile()
 
+    def phase_stats(self) -> dict[str, dict[str, float]]:
+        """Host seconds of each ``serve.*`` span of ``submit`` since the
+        server was built: ``{name: {"count", "total_s", "max_s"}}``."""
+        return self._phases.stats()
+
     def submit(self, template: PlanTemplate | int,
                bindings: dict[str, Any] | None = None,
                infer: bool | None = None,
@@ -248,13 +256,25 @@ class QueryServer:
         """
         if isinstance(template, int):
             template = TEMPLATES[template]
+        self._requests += 1
+        with self._phases.span(tracing.SUBMIT, request=self._requests,
+                               template=template.name):
+            return self._submit(template, bindings, infer, tolerance,
+                                confidence)
+
+    def _submit(self, template: PlanTemplate, bindings, infer, tolerance,
+                confidence) -> dict:
+        span = self._phases.span
         if infer is None:
             infer = planner.planner_default()
-        bound = template.bind(**(bindings or {}))
-        # dtype-pinned traced scalars; every declared parameter is always
-        # present, so the pytree structure (and hence the trace) is stable
-        pvals = {name: jnp.asarray(v, _PDTYPE[template.params[name].dtype])
-                 for name, v in bound.values.items()}
+        with span(tracing.BIND):
+            bound = template.bind(**(bindings or {}))
+            # dtype-pinned traced scalars; every declared parameter is
+            # always present, so the pytree structure (and hence the
+            # trace) is stable
+            pvals = {name: jnp.asarray(v,
+                                       _PDTYPE[template.params[name].dtype])
+                     for name, v in bound.values.items()}
         if tolerance is None:
             from repro.approx.progressive import approx_default
             tolerance = approx_default()
@@ -264,24 +284,31 @@ class QueryServer:
             if res is not None:
                 return res
             self.approx_refused += 1
-        fn = self._executable(template, infer, self.capacity_factor)
-        out, overflow, corrupt = fn(self._tables, pvals)
-        if bool(overflow):
+        with span(tracing.LOOKUP):
+            fn = self._executable(template, infer, self.capacity_factor)
+        with span(tracing.DISPATCH):
+            out, overflow, corrupt = fn(self._tables, pvals)
+        with span(tracing.WAIT):
+            overflowed = bool(overflow)
+        if overflowed:
             # a domain-derived claim was too tight for this binding (or the
             # statistics lied): re-run conservatively — no hints, escalated
             # capacity, full-width wire — under its own cache key so healthy
             # traffic keeps the fast entry
-            self.overflow_reruns += 1
-            fn = self._executable(template, False,
-                                  self.capacity_factor * 4.0)
-            out, overflow, corrupt = fn(self._tables, pvals)
+            with span(tracing.RERUN):
+                self.overflow_reruns += 1
+                fn = self._executable(template, False,
+                                      self.capacity_factor * 4.0)
+                out, overflow, corrupt = fn(self._tables, pvals)
+                overflowed = bool(overflow)
         if bool(corrupt):
             raise CorruptPayload("serve: payload integrity check failed")
-        if bool(overflow):
+        if overflowed:
             raise RuntimeError(
                 f"{template.name}: overflow persists on the conservative "
                 f"rerun (capacity_factor={self.capacity_factor * 4.0})")
-        return to_numpy(out)
+        with span(tracing.FETCH):
+            return to_numpy(out)
 
     # -- approximate serving (repro.approx) --------------------------------
     def _approx_rewrite(self, template: PlanTemplate, den: int):
