@@ -17,7 +17,10 @@ programs (§4.4) — and is counted identically on every backend so plan statist
 ``join_method`` selects the per-device join engine on the JAX backends:
 ``"sorted"`` (searchsorted probe, always available) or ``"hash"`` (Pallas
 bucket-table probe); both paths are byte-identical (tests/test_sort_tax.py)
-and share the per-plan build-side cache on ``_BaseContext``.
+and share the per-plan build-side cache on ``_BaseContext``.  Under
+``"sorted"``, a join whose build key the planner proved to lie in a dense
+``key_range`` takes the direct-address index instead (one gather per probe
+row, ``rel.direct_join_fits``), with the same answers.
 """
 from __future__ import annotations
 
@@ -66,6 +69,8 @@ class PlanStats:
     allreduces: int = 0
     overflow_checks: int = 0
     log: list = dataclasses.field(default_factory=list)
+    # join indexes built, by the method each took: direct / sorted / hash
+    index_builds: dict = dataclasses.field(default_factory=dict)
 
     def counts(self):
         return {"shuffles": self.shuffles, "broadcasts": self.broadcasts,
@@ -102,10 +107,10 @@ class _BaseContext:
     """Shared bookkeeping + derived helpers.
 
     ``_join_cache`` is the per-query build-side cache: a (build table, key)
-    pair is indexed (sorted or bucket-hashed) at most once per plan, however
-    many joins probe it — dimension tables stop paying one build sort per
-    join.  The cache holds a strong reference to the build table so ``id()``
-    keys stay unique for the context's (= one plan's) lifetime.
+    pair is indexed (direct, sorted or bucket-hashed) at most once per plan,
+    however many joins probe it — dimension tables stop paying one build
+    per join.  The cache holds a strong reference to the build table so
+    ``id()`` keys stay unique for the context's (= one plan's) lifetime.
     """
 
     join_method = "sorted"  # "sorted" (searchsorted) | "hash" (Pallas probe)
@@ -281,19 +286,21 @@ class RefContext(_BaseContext):
             return t[on]
         return ref.combine_keys([t[c] for c in on])
 
-    def join(self, probe, build, probe_on, build_on, take):
+    # key_range is a JAX-engine index hint; the oracle ignores it
+    def join(self, probe, build, probe_on, build_on, take, key_range=None):
         return ref.join_unique(probe, build, self._key(probe, probe_on),
                                self._key(build, build_on), take)
 
-    def semi(self, probe, build, probe_on, build_on):
+    def semi(self, probe, build, probe_on, build_on, key_range=None):
         return ref.semi_join(probe, build, self._key(probe, probe_on),
                              self._key(build, build_on))
 
-    def anti(self, probe, build, probe_on, build_on):
+    def anti(self, probe, build, probe_on, build_on, key_range=None):
         return ref.anti_join(probe, build, self._key(probe, probe_on),
                              self._key(build, build_on))
 
-    def left(self, probe, build, probe_on, build_on, take, defaults):
+    def left(self, probe, build, probe_on, build_on, take, defaults,
+             key_range=None):
         return ref.left_join(probe, build, self._key(probe, probe_on),
                              self._key(build, build_on), take, defaults)
 
@@ -395,49 +402,59 @@ class LocalContext(_BaseContext):
             return t[on]
         return rel.combine_keys([t[c] for c in on])
 
-    def _build_index(self, build, build_on) -> rel.BuildIndex:
-        """Per-plan build cache: index each (build table, key) pair once."""
+    def _build_index(self, build, build_on, key_range=None) -> rel.BuildIndex:
+        """Per-plan build cache: index each (build table, key) pair once.
+
+        ``key_range`` is the planner's proven ``(lo, hi)`` of the build key;
+        with it the sorted engine builds the direct-address index (which
+        falls back to sorted where the domain is too sparse)."""
         if isinstance(build_on, str):
-            on_desc = build_on
+            ck = (id(build), build_on, key_range)
         elif isinstance(build_on, (list, tuple)) and \
                 all(isinstance(c, str) for c in build_on):
-            on_desc = tuple(build_on)
+            ck = (id(build), tuple(build_on), key_range)
         else:  # raw key arrays etc. — build fresh rather than key by id()
-            idx = rel.build_index(build, self._key(build, build_on),
-                                  method=self.join_method,
-                                  bucket_cap=self.bucket_cap())
-            self.overflow = self.overflow | idx.overflow
-            return idx
-        ck = (id(build), on_desc)
-        hit = self._join_cache.get(ck)
+            ck = None
+        hit = self._join_cache.get(ck) if ck is not None else None
         if hit is not None:
             return hit[1]
+        method = self.join_method
+        if method == "sorted" and key_range is not None:
+            method = "direct"
         idx = rel.build_index(build, self._key(build, build_on),
-                              method=self.join_method,
-                              bucket_cap=self.bucket_cap())
+                              method=method, bucket_cap=self.bucket_cap(),
+                              key_range=key_range)
         self.overflow = self.overflow | idx.overflow
-        self._join_cache[ck] = (build, idx)  # keep build alive: id() stability
+        builds = self.stats.index_builds
+        builds[idx.method] = builds.get(idx.method, 0) + 1
+        if ck is not None:
+            self._join_cache[ck] = (build, idx)  # keep build alive: id()
         return idx
 
-    def join(self, probe, build, probe_on, build_on, take):
+    def join(self, probe, build, probe_on, build_on, take, key_range=None):
         return rel.join_unique(probe, build, self._key(probe, probe_on),
                                self._key(build, build_on), take,
-                               index=self._build_index(build, build_on))
+                               index=self._build_index(build, build_on,
+                                                       key_range))
 
-    def semi(self, probe, build, probe_on, build_on):
+    def semi(self, probe, build, probe_on, build_on, key_range=None):
         return rel.semi_join(probe, build, self._key(probe, probe_on),
                              self._key(build, build_on),
-                             index=self._build_index(build, build_on))
+                             index=self._build_index(build, build_on,
+                                                     key_range))
 
-    def anti(self, probe, build, probe_on, build_on):
+    def anti(self, probe, build, probe_on, build_on, key_range=None):
         return rel.anti_join(probe, build, self._key(probe, probe_on),
                              self._key(build, build_on),
-                             index=self._build_index(build, build_on))
+                             index=self._build_index(build, build_on,
+                                                     key_range))
 
-    def left(self, probe, build, probe_on, build_on, take, defaults):
+    def left(self, probe, build, probe_on, build_on, take, defaults,
+             key_range=None):
         return rel.left_join(probe, build, self._key(probe, probe_on),
                              self._key(build, build_on), take, defaults,
-                             index=self._build_index(build, build_on))
+                             index=self._build_index(build, build_on,
+                                                     key_range))
 
     def group_by(self, t, keys, aggs, exchange="local", final=False,
                  groups_hint=None, key_bits=None, wire=None, method="auto"):

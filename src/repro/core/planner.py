@@ -844,9 +844,18 @@ class PlanInfo:
     # unprovable — the data-dependent-domain shape (Q13) the direct path
     # cannot take, extended to zero sorts by the trace-time dictionary.
     methods: dict[int, str] = dataclasses.field(default_factory=dict)
+    # per join / semi / anti / left join on one integer build column: the
+    # provable (lo, hi) of its build key, which lets the engine index the
+    # build by direct addressing (re-checked at run time: a valid key
+    # outside the range raises the overflow flag)
+    join_ranges: dict[int, tuple[int, int]] = \
+        dataclasses.field(default_factory=dict)
 
     def hints_for(self, node: P.GroupBy):
         return self.group_hints.get(id(node), (None, None))
+
+    def join_range_for(self, node: P._JoinBase) -> tuple[int, int] | None:
+        return self.join_ranges.get(id(node))
 
     def method_for(self, node: P.GroupBy) -> str | None:
         return self.methods.get(id(node))
@@ -1109,8 +1118,18 @@ def analyze(root: P.Node, db) -> PlanInfo:
             # (avg's sum/count temporaries are unbounded and ship full-width)
             wire[id(n)] = _payload_bounds(schema(n))
 
+    # -- join build-key ranges --------------------------------------------
+    # a single-column build key with provable integer bounds; multi-column
+    # keys (packed at run time) keep the sorted index
+    join_ranges: dict[int, tuple[int, int]] = {}
+    for n in nodes:
+        if isinstance(n, P._JoinBase) and isinstance(n.build_on, str):
+            s = schema(n.build).get(n.build_on, _UNKNOWN)
+            if _is_int(s.lo) and _is_int(s.hi):
+                join_ranges[id(n)] = (int(s.lo), int(s.hi))
+
     return PlanInfo(hints, parts, notes, static_plan_stats(root), wire,
-                    methods)
+                    methods, join_ranges)
 
 
 def validate(root: P.Node, db) -> list[str]:
@@ -1262,19 +1281,22 @@ class _Executor:
                 for k, e in node.exprs.items()})
         if isinstance(node, P.Rename):
             return ctx.rename(self._exec(node.children[0]), node.mapping)
-        if isinstance(node, P.Join):
-            return ctx.join(self._exec(node.probe), self._exec(node.build),
-                            node.on, node.build_on, list(node.take))
-        if isinstance(node, P.Semi):
-            return ctx.semi(self._exec(node.probe), self._exec(node.build),
-                            node.on, node.build_on)
-        if isinstance(node, P.Anti):
-            return ctx.anti(self._exec(node.probe), self._exec(node.build),
-                            node.on, node.build_on)
-        if isinstance(node, P.Left):
-            return ctx.left(self._exec(node.probe), self._exec(node.build),
-                            node.on, node.build_on, list(node.take),
-                            node.defaults)
+        if isinstance(node, P._JoinBase):
+            # inference off (the conservative leg): no range, sorted index
+            rng = self.info.join_range_for(node) if self.info is not None \
+                else None
+            probe, build = self._exec(node.probe), self._exec(node.build)
+            if isinstance(node, P.Join):
+                return ctx.join(probe, build, node.on, node.build_on,
+                                list(node.take), key_range=rng)
+            if isinstance(node, P.Semi):
+                return ctx.semi(probe, build, node.on, node.build_on,
+                                key_range=rng)
+            if isinstance(node, P.Anti):
+                return ctx.anti(probe, build, node.on, node.build_on,
+                                key_range=rng)
+            return ctx.left(probe, build, node.on, node.build_on,
+                            list(node.take), node.defaults, key_range=rng)
         if isinstance(node, P.GroupBy):
             t = self._exec(node.children[0])
             if self.info is not None:

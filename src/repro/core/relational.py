@@ -2,11 +2,13 @@
 
 TPU adaptation (DESIGN.md §2): no atomics / no dynamic shapes, so
   * filter        = O(n) validity-mask merge (deferred compaction — no sort)
-  * hash join     = sorted-build ``searchsorted`` probe, or the Pallas
-                    bucket-table probe (``kernels/hash_probe``) behind a
-                    dispatch flag; build sides index once per plan via
-                    :class:`BuildIndex` (unique build keys — every TPC-H join
-                    is FK->PK once plans order probe/build sides)
+  * join          = one of three :class:`BuildIndex` methods, each built
+                    once per plan per build side (unique build keys — every
+                    TPC-H join is FK->PK once plans order probe/build sides):
+                    ``direct`` (a slot per value of a provably dense key
+                    domain, one gather per probe row), ``sorted`` (sorted
+                    build, ``searchsorted`` probe) or ``hash`` (the Pallas
+                    bucket-table probe, ``kernels/hash_probe``)
   * group-by      = sortless when the key domain is provably small (dense
                     group ids + the ``kernels/segsum`` one-hot MXU reduce —
                     aggregation-as-matmul); otherwise ONE stable argsort over
@@ -27,8 +29,11 @@ front-compaction runs only where contiguity is genuinely required:
 Sort-count budget per operator (HLO ``sort`` ops; enforced by
 ``benchmarks/bench_sort_tax.py`` and the CI regression gate):
 
-  filter_rows / semi / anti      0
-  join_unique / left_join        0 probe-side + 1 per *distinct* build index
+  filter_rows                    0
+  join_unique / left_join /      0 probe-side; per *distinct* build index
+    semi / anti                  0 on the direct path (planner-proven dense
+                                 key domain, ``DIRECT_JOIN_SPAN``), 1 on the
+                                 sorted path
   group_aggregate                0 with provable ``key_bits`` (packed domain
                                  <= 2^13: direct addressing via the segsum
                                  one-hot kernel), 0 with a claimed
@@ -40,10 +45,12 @@ Sort-count budget per operator (HLO ``sort`` ops; enforced by
   compact / ensure_compact       1, boundaries only
 
 ``key_bits`` is no longer hand-threaded by query code: ``core/planner.py``
-derives it (and ``groups_hint``) by bound propagation over the logical plan
-(``core/plan.py``) and passes it here — the physical contract of this module
-is unchanged, only the *source* of the widths moved from comments at call
-sites into a compiler pass.
+derives it (and ``groups_hint``, and each join's build-key ``key_range``) by
+bound propagation over the logical plan (``core/plan.py``) and passes it
+here — the physical contract of this module is unchanged, only the *source*
+of the widths moved from comments at call sites into a compiler pass.  Every
+such claim is re-checked at run time: a valid row outside it raises the
+overflow flag, never a silent miss.
 
 The joins, the group-by, compaction and ordering trace inside their
 ``rel.*`` operator scopes (``core/tracing.py``), so a profile charges each
@@ -72,6 +79,15 @@ from repro.kernels.segsum import ops as _ss_ops
 # 64 lane-tiles) is the practical MXU ceiling; larger domains fall back to
 # the single-sort path.
 DIRECT_AGG_BITS_MAX = 13
+# Most slots per build row a direct-address join index may spend: the index
+# holds one int32 slot per value of the proven key domain [lo, hi].  TPC-H's
+# primary keys are dense; at sf 1 the ratio is 4 for o_orderkey (6 000 000
+# values over 1 500 000 orders) and 1 for c_custkey, p_partkey, s_suppkey and
+# n_nationkey.  A sparser domain falls back to the sorted index.
+DIRECT_JOIN_SPAN = 8
+# Most slots of a direct-address join index whatever the build's size: 2^26
+# int32 slots are 256 MB, 1.6 % of a v5e chip's 16 GB of HBM.
+DIRECT_JOIN_SLOTS_MAX = 1 << 26
 # Largest table an ORDER BY ranks pairwise instead of sorting, on TPU only:
 # XLA:TPU took 475 s to compile a stable (float64, int64) sort of 33,280
 # rows for v5e, while ranking 2^18 rows pairwise is ~7*10^10 compares.
@@ -116,6 +132,7 @@ __all__ = [
     "combine_keys",
     "BuildIndex",
     "build_index",
+    "direct_join_fits",
     "probe_index",
     "join_unique",
     "semi_join",
@@ -241,9 +258,13 @@ class BuildIndex:
     """Reusable probe structure over a unique-key build side.
 
     Built once per (build table, key) pair and cached per plan by the backend
-    contexts, so a dimension table probed by several joins pays its build sort
-    once.  Two methods:
+    contexts, so a dimension table probed by several joins is indexed once.
+    Three methods:
 
+      * ``direct``: one int32 slot per value of a key domain ``[lo, hi]``
+        the planner proved, holding the build row with that key or -1; a
+        probe is one gather.  Built by one scatter, no sort.  A valid build
+        key outside the domain raises ``overflow``.
       * ``sorted``: keys sorted once, probes are ``searchsorted`` (pure JAX —
         the always-available fallback).
       * ``hash``: (B, C) bucket table of 32-bit key planes probed by the
@@ -254,6 +275,9 @@ class BuildIndex:
     method: str
     capacity: int
     overflow: jax.Array
+    # direct: slots[key - lo] = build row, or -1
+    lo: int | None = None
+    slots: jax.Array | None = None
     # sorted
     sorted_keys: jax.Array | None = None
     sorted_rows: jax.Array | None = None
@@ -263,11 +287,34 @@ class BuildIndex:
     bvals: jax.Array | None = None
 
 
+def direct_join_fits(key_range: tuple[int, int] | None,
+                     capacity: int) -> bool:
+    """Whether a build of ``capacity`` rows whose keys lie in ``key_range``
+    takes the direct-address index: at most ``DIRECT_JOIN_SPAN`` slots per
+    build row and ``DIRECT_JOIN_SLOTS_MAX`` slots in all."""
+    if key_range is None:
+        return False
+    span = key_range[1] - key_range[0] + 1
+    return 1 <= span <= min(DIRECT_JOIN_SPAN * max(1, capacity),
+                            DIRECT_JOIN_SLOTS_MAX)
+
+
 @tracing.op_scope(tracing.JOIN_BUILD)
 def build_index(build: Table, build_key: jax.Array, method: str = "sorted",
-                bucket_cap: int = 16) -> BuildIndex:
-    """Index the build side of a unique-key join (one argsort either way)."""
+                bucket_cap: int = 16,
+                key_range: tuple[int, int] | None = None) -> BuildIndex:
+    """Index the build side of a unique-key join.
+
+    ``method="direct"`` needs ``key_range``, the proven ``(lo, hi)`` of the
+    valid build keys, and falls back to ``sorted`` where
+    :func:`direct_join_fits` refuses the domain."""
     bkey = _valid_key(build, build_key)
+    if method == "direct":
+        if key_range is None:
+            raise ValueError("direct join index needs a key_range")
+        if direct_join_fits(key_range, build.capacity):
+            return _build_direct(bkey, build.capacity, *key_range)
+        method = "sorted"
     if method == "sorted":
         order = jnp.argsort(bkey)
         return BuildIndex("sorted", build.capacity, jnp.asarray(False),
@@ -282,12 +329,36 @@ def build_index(build: Table, build_key: jax.Array, method: str = "sorted",
                       bk_lo=bk_lo, bk_hi=bk_hi, bvals=bv)
 
 
+def _build_direct(bkey: jax.Array, cap: int, lo: int, hi: int) -> BuildIndex:
+    """Direct-address index over the key domain ``[lo, hi]``: one scatter of
+    each valid build row's index to its key's slot.  Where keys repeat (a
+    semi-join's build) the slot keeps the lowest row, the row the sorted
+    index finds.  Invalid rows (sentinel keys) are dropped; a valid key
+    outside the domain raises the overflow flag, so a stale or lying range
+    reruns conservatively instead of missing matches."""
+    span = hi - lo + 1
+    valid = bkey != KEY_SENTINEL
+    in_range = (bkey >= lo) & (bkey <= hi)
+    off = jnp.where(valid & in_range, bkey - lo, span).astype(jnp.int32)
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    slots = jnp.full((span,), cap, jnp.int32).at[off].min(rows, mode="drop")
+    return BuildIndex("direct", cap, jnp.any(valid & ~in_range), lo=lo,
+                      slots=jnp.where(slots == cap, -1, slots))
+
+
 @tracing.op_scope(tracing.JOIN_PROBE)
 def probe_index(index: BuildIndex, probe_key: jax.Array,
                 probe_valid: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Probe an index.  Returns (matched, build_row_idx); idx arbitrary where
     unmatched (callers mask through ``matched``)."""
     pk = probe_key.astype(_I64)
+    if index.method == "direct":
+        hi = index.lo + index.slots.shape[0] - 1
+        in_range = (pk >= index.lo) & (pk <= hi) & probe_valid & \
+            (pk != KEY_SENTINEL)
+        row = index.slots[jnp.where(in_range, pk - index.lo, 0)
+                          .astype(jnp.int32)]
+        return in_range & (row >= 0), jnp.maximum(row, 0)
     if index.method == "sorted":
         pos = jnp.searchsorted(index.sorted_keys, pk)
         pos = jnp.minimum(pos, index.capacity - 1)

@@ -7,9 +7,10 @@ finds them after a refactor:
   in ``jax.named_scope``.  They change only the ``op_name`` metadata of the
   HLO the operator emits, never the operations, so every device operation
   of a profiled program can be charged to the operator that emitted it,
-  whatever implements it (a ``searchsorted`` binary search or a Pallas
-  probe kernel).  Where scopes nest (a ``compact`` inside a group-by) the
-  innermost ``rel.*`` component of an ``op_name`` is the owner.
+  whatever implements it (a direct-address gather, a ``searchsorted``
+  binary search or a Pallas probe kernel).  Where scopes nest (a
+  ``compact`` inside a group-by) the innermost ``rel.*`` component of an
+  ``op_name`` is the owner.
 * **Host spans** (``serve.*``) mark the phases of ``QueryServer.submit``.
   Each opens a ``jax.profiler.TraceAnnotation``, which a running profiler
   records on its host plane on the device trace's clock, and adds its host
@@ -26,8 +27,10 @@ import time
 import jax
 
 # operator scopes (core/relational.py)
-JOIN_BUILD = "rel.join_build"    # build_index: build-side argsort or buckets
-JOIN_PROBE = "rel.join_probe"    # probe_index: searchsorted or hash probe
+JOIN_BUILD = "rel.join_build"    # build_index: the direct-address scatter,
+                                 # the build-side argsort or the buckets
+JOIN_PROBE = "rel.join_probe"    # probe_index: the direct-address gather,
+                                 # searchsorted or the hash probe
 JOIN_TAKE = "rel.join_take"      # gathers of build columns through the index
 GROUP_BY = "rel.group_by"        # group_aggregate, every path
 COMPACT = "rel.compact"          # front compaction

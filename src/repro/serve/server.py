@@ -60,6 +60,8 @@ __all__ = ["QueryServer", "BatchExecutor", "AdmissionGate",
            "Served", "Degraded", "Shed"]
 
 _PDTYPE = {"int64": jnp.int64, "float64": jnp.float64}
+# capacity escalation of the conservative rerun after an overflow
+_RERUN_FACTOR = 4.0
 
 
 def _as_table(out):
@@ -143,6 +145,8 @@ class QueryServer:
         self.approx_escalations = 0  # tolerance misses climbed past
         self.approx_refused = 0      # non-estimable shapes served exact
         self._requests = 0           # submit's sequence number
+        # join indexes each exact program builds, by method (trace time)
+        self._index_builds: dict[tuple, dict[str, int]] = {}
         self._phases = tracing.Phases()
         self._tables = B._np_db_to_tables(db)
         # topology state: logical width this server answers on behalf of
@@ -190,19 +194,40 @@ class QueryServer:
         factor = self.capacity_factor if factor is None else factor
         return self._db_bytes / self.devices * (1.0 + float(factor))
 
+    def _exe_key(self, template: PlanTemplate, infer: bool,
+                 factor: float) -> tuple:
+        return ("exe", template.signature(), bool(infer), self.wire_format,
+                float(factor), self.join_method, self.use_kernel,
+                self.topology_generation)
+
     def _executable(self, template: PlanTemplate, infer: bool, factor: float):
-        key = ("exe", template.signature(), bool(infer), self.wire_format,
-               float(factor), self.join_method, self.use_kernel,
-               self.topology_generation)
+        key = self._exe_key(template, infer, factor)
         fn = self.cache.get(self.db, key)
         if fn is None:
-            fn = self._compile(template, infer, factor)
+            fn = self._compile(template, infer, factor, key)
             self.cache.put(self.db, key, fn)
         else:
             self.cache_hits += 1
         return fn
 
-    def _compile(self, template: PlanTemplate, infer: bool, factor: float):
+    def index_builds(self, template: PlanTemplate | int,
+                     infer: bool | None = None,
+                     rerun: bool = False) -> dict[str, int] | None:
+        """Join indexes the template's exact program builds, by method
+        (``{"direct": 3, "sorted": 1}``), as its last trace counted them;
+        ``rerun=True`` reads the conservative rerun's program.  None where
+        that program has not been traced."""
+        if isinstance(template, int):
+            template = TEMPLATES[template]
+        if infer is None:
+            infer = planner.planner_default()
+        key = self._exe_key(template, infer and not rerun,
+                            self.capacity_factor *
+                            (_RERUN_FACTOR if rerun else 1.0))
+        return self._index_builds.get(key)
+
+    def _compile(self, template: PlanTemplate, infer: bool, factor: float,
+                 key: tuple):
         query = template.query
         # host-side, once per (template, db): domain-sound hints/wire bounds
         info = query.info(self.db) if infer else None
@@ -216,6 +241,7 @@ class QueryServer:
                                  use_kernel=self.use_kernel,
                                  wire_format=self.wire_format)
             out = planner._Executor(ctx, info, params=pvals).run(query.plan)
+            self._index_builds[key] = dict(ctx.stats.index_builds)
             return _as_table(out), ctx.overflow, ctx.corrupt
 
         return jax.jit(run)
@@ -298,7 +324,7 @@ class QueryServer:
             with span(tracing.RERUN):
                 self.overflow_reruns += 1
                 fn = self._executable(template, False,
-                                      self.capacity_factor * 4.0)
+                                      self.capacity_factor * _RERUN_FACTOR)
                 out, overflow, corrupt = fn(self._tables, pvals)
                 overflowed = bool(overflow)
         if bool(corrupt):
@@ -306,7 +332,8 @@ class QueryServer:
         if overflowed:
             raise RuntimeError(
                 f"{template.name}: overflow persists on the conservative "
-                f"rerun (capacity_factor={self.capacity_factor * 4.0})")
+                f"rerun (capacity_factor="
+                f"{self.capacity_factor * _RERUN_FACTOR})")
         with span(tracing.FETCH):
             return to_numpy(out)
 
@@ -376,7 +403,7 @@ class QueryServer:
             if bool(overflow):
                 self.overflow_reruns += 1
                 fn, tables = self._approx_executable(
-                    template, rw, False, self.capacity_factor * 4.0)
+                    template, rw, False, self.capacity_factor * _RERUN_FACTOR)
                 out, overflow, corrupt = fn(tables, pvals)
             if bool(corrupt):
                 raise CorruptPayload(
@@ -544,7 +571,7 @@ class BatchExecutor:
 
     def _conservative(self, bound: BoundQuery) -> dict:
         self.overflow_reruns += 1
-        ctx = self._ctx(self.capacity_factor * 4.0)
+        ctx = self._ctx(self.capacity_factor * _RERUN_FACTOR)
         out = _as_table(bound.with_inference(False)(ctx))
         if bool(ctx.corrupt):
             raise CorruptPayload("batch: payload integrity check failed")

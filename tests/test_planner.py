@@ -80,7 +80,8 @@ def test_no_hand_key_bits_left_in_query_code():
 def test_inference_on_off_byte_identical(db, qid):
     """The compiled hinted path and the conservative unhinted path must agree
     bit for bit on the local backend — the planner cannot silently diverge
-    from the legacy eager semantics.
+    from the legacy eager semantics.  Hinted joins on a proven dense key
+    take the direct-address index; unhinted ones keep the sorted index.
 
     Byte identity holds per aggregation engine: under REPRO_AGG_KERNEL=1 the
     hinted direct path sums on the (interpret-mode) MXU one-hot kernel while
@@ -100,6 +101,47 @@ def test_inference_on_off_byte_identical(db, qid):
             np.testing.assert_array_equal(r_on[k], r_off[k],
                                           err_msg=f"q{qid} {k}")
     assert s_on.counts() == s_off.counts()   # hints never move exchanges
+    # inference off builds every join index sorted, as before the planner
+    # proved key ranges; inference on builds the same number of indexes
+    assert set(s_off.index_builds) <= {"sorted"}
+    assert sum(s_on.index_builds.values()) == \
+        sum(s_off.index_builds.values())
+
+
+# Join indexes each benchmarked query builds with inference on, by method:
+# every single-column join on the direct path; Q9 (a control, not
+# benchmarked) keeps its two-column partsupp join on the sorted index.
+_INDEX_BUILDS = {1: {}, 3: {"direct": 2}, 5: {"direct": 5}, 6: {},
+                 9: {"direct": 3, "sorted": 1}, 10: {"direct": 2},
+                 12: {"direct": 1}, 14: {"direct": 1}, 18: {"direct": 2},
+                 19: {"direct": 1}}
+
+
+@pytest.mark.parametrize("qid", sorted(_INDEX_BUILDS))
+def test_join_index_methods(db, qid):
+    """The engagement counter: which index each join of a plan builds, as
+    the trace counts it (nothing runs)."""
+    import jax
+    tables = B._np_db_to_tables(db)
+
+    def builds(infer):
+        ctx = {}
+
+        def run(tables):
+            ctx["c"] = B.LocalContext(db, tables)
+            return QUERIES[qid].run(ctx["c"], infer=infer)
+
+        jax.eval_shape(run, tables)
+        return ctx["c"].stats.index_builds
+
+    assert builds(True) == _INDEX_BUILDS[qid]
+    n = sum(_INDEX_BUILDS[qid].values())
+    assert builds(False) == ({"sorted": n} if n else {})
+    ranges = [QUERIES[qid].info(db).join_range_for(n)
+              for n in PL.walk(QUERIES[qid].plan)
+              if isinstance(n, P._JoinBase)]
+    assert sum(r is None for r in ranges) == \
+        _INDEX_BUILDS[qid].get("sorted", 0)
 
 
 @pytest.mark.parametrize("qid", sorted(QUERIES))

@@ -350,6 +350,39 @@ def test_server_overflow_recovers_conservatively(db):
     assert (out["n"] >= 1).all()
 
 
+def test_server_build_key_outside_planned_range_reruns():
+    """Tables mutated after the planner proved a join's build-key range
+    (stale statistics): the direct-address index sees a valid key outside
+    the range and raises overflow; the server counts one conservative rerun,
+    whose sorted index gives the reference answer."""
+    db2 = tpch.generate(0.002, seed=5)
+    t12 = serve.TEMPLATES[12]
+    assert planner.column_stats(db2)          # statistics proven now
+    orders, li = db2.tables["orders"], db2.tables["lineitem"]
+    ok, lk = np.asarray(orders["o_orderkey"]), np.asarray(li["l_orderkey"])
+    hi = int(ok.max())
+    # move the orders holding Q12's qualifying lineitems above the range
+    moved = np.unique(lk[np.isin(np.asarray(li["l_shipmode"]),
+                                 db2.codes("l_shipmode", ["MAIL", "SHIP"]))])
+    moved = moved[:20]
+    remap = {int(k): hi + 1 + i for i, k in enumerate(moved)}
+    orders["o_orderkey"] = np.asarray([remap.get(int(k), k) for k in ok],
+                                      dtype=ok.dtype)
+    li["l_orderkey"] = np.asarray([remap.get(int(k), k) for k in lk],
+                                  dtype=lk.dtype)
+    srv = serve.QueryServer(db2)      # tables snapshot taken at server build
+    out = srv.submit(t12, infer=True)
+    assert srv.overflow_reruns == 1
+    assert srv.index_builds(t12, infer=True) == {"direct": 1}
+    assert srv.index_builds(t12, rerun=True) == {"sorted": 1}
+    ref, _ = B.run_reference(t12.bind(), db2)
+    assert set(out) == set(ref)
+    for k in ref:
+        np.testing.assert_allclose(np.asarray(out[k], np.float64),
+                                   np.asarray(ref[k], np.float64),
+                                   rtol=1e-12, err_msg=k)
+
+
 def test_batch_overflow_isolated(db):
     """A lying request re-runs conservatively; its neighbours (before AND
     after it in the batch) stay byte-identical to sequential execution."""

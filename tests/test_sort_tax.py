@@ -4,7 +4,8 @@ Covers the three tentpole invariants:
   * masked (uncompacted) tables produce identical results to eagerly
     compacted ones across filter/join/group-by chains;
   * the Pallas hash-probe join path is byte-identical to the searchsorted
-    path on all 22 TPC-H queries (with the NumPy RefContext as oracle);
+    path on all 22 TPC-H queries (with the NumPy RefContext as oracle), and
+    the direct-address join index sorts nothing and loops nothing;
   * the HLO ``sort`` op count of representative local plans stays within the
     post-optimization budget (the CI gate runs the fuller check in
     ``benchmarks/bench_sort_tax.py``).
@@ -180,19 +181,29 @@ def test_hash_join_path_byte_identical(db, qid):
 
 
 # Absolute per-query HLO sort budgets for the local plans (phase 2/3:
-# planner-inferred group-bys are sortless, shuffle dispatch is sortless).
-# Tighter than the seed-relative 40% rule; the fuller gate lives in
-# benchmarks/bench_sort_tax.py.  Compiled with inference pinned ON so the
-# REPRO_PLANNER=0 CI leg measures the same program.
+# planner-inferred group-bys are sortless, shuffle dispatch is sortless,
+# joins on a proven dense build key use the direct-address index, which
+# sorts nothing).  Tighter than the seed-relative 40% rule; the fuller gate
+# lives in benchmarks/bench_sort_tax.py.  Compiled with inference pinned ON
+# so the REPRO_PLANNER=0 CI leg measures the same program.
 #   q1  = 1 final ORDER BY              (group-by direct, was 2)
-#   q3  = 4 (3 once the planner proves l_orderkey's width at this SF)
+#   q3  = 1 final ORDER BY   (both build indexes direct; the l_orderkey
+#         group-by is direct at this SF — 2 where it is not, as at the
+#         bench's sf 0.01; was 4)
+#   q5  = 1 final ORDER BY              (five direct build indexes)
 #   q6  = 0 (scalar aggregation is the trivial direct domain)
-#   q9  = 4 build indexes + 1 final ORDER BY (group-by direct, was 6)
-#   q12 = 1 build index + 1 final ORDER BY   (group-by direct, was 3)
-#   q13 = 1 build index + 1 final ORDER BY   (c_count group-by rides the
-#         hash-compaction dictionary — data-dependent domain, zero sorts —
-#         and the o_custkey group-by is direct; was 3)
-_MAX_SORTS = {1: 1, 3: 4, 6: 0, 9: 5, 12: 2, 13: 2}
+#   q9  = 1 build index (the two-column partsupp key: sorted) + 1 final
+#         ORDER BY (group-by direct, was 6, then 5)
+#   q10 = 1 final ORDER BY              (both build indexes direct)
+#   q12 = 1 final ORDER BY   (build index direct, group-by direct, was 3)
+#   q13 = 1 final ORDER BY   (build index direct; the c_count group-by
+#         rides the hash-compaction dictionary — data-dependent domain,
+#         zero sorts — and the o_custkey group-by is direct; was 3)
+#   q14 = 0, q19 = 0 (one direct build index, scalar aggregation)
+#   q18 = 1 compaction (the shrink before the broadcast) + 1 final ORDER
+#         BY (both build indexes direct)
+_MAX_SORTS = {1: 1, 3: 1, 5: 1, 6: 0, 9: 2, 10: 1, 12: 1, 13: 1, 14: 0,
+              18: 2, 19: 0}
 
 
 @pytest.mark.parametrize("qid", sorted(_MAX_SORTS))
@@ -211,6 +222,27 @@ def test_hlo_sort_count_budget(db, qid):
     nsort = op_histogram(hlo, ops=("sort",))["sort"]
     assert nsort <= _MAX_SORTS[qid], \
         f"q{qid}: {nsort} HLO sorts > budget {_MAX_SORTS[qid]}"
+
+
+def test_direct_join_zero_sorts_no_loop():
+    """A join through the direct-address index lowers to ZERO HLO sorts and
+    no while loop: one scatter builds it, one gather probes it (the sorted
+    index's argsort and searchsorted loop are both gone)."""
+    t = _random_table(12)
+    build = from_numpy({"bk": np.arange(3, 18, dtype=np.int64),
+                        "bv": np.arange(15) * 0.5}, capacity=16)
+
+    def run(t, build):
+        idx = R.build_index(build, build["bk"], method="direct",
+                            key_range=(3, 17))
+        assert idx.method == "direct"
+        out = R.join_unique(t, build, t["k"], build["bk"], ["bv"],
+                            index=idx)
+        return out["bv"], out.valid_mask(), idx.overflow
+
+    hlo = jax.jit(run).lower(t, build).compile().as_text()
+    counts = op_histogram(hlo, ops=("sort", "while"))
+    assert counts == {"sort": 0, "while": 0}, counts
 
 
 def test_group_aggregate_with_key_bits_zero_sorts():

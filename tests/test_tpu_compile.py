@@ -135,6 +135,26 @@ def test_q1_program_compiles_for_v5e(one_chip, compiled_kernels):
     assert "tpu_custom_call" in text
 
 
+def test_direct_join_compiles_for_v5e(one_chip):
+    """The direct-address join index at chip size (a 2^20-row build over a
+    domain four times as wide, probed by 2^22 rows) compiles for v5e to one
+    scatter and one gather: no sort, no loop."""
+    from repro.core.table import Table
+    from repro.distributed.hlo_analysis import op_histogram
+    span = 4 * N
+
+    def join(bk, pk):
+        build = Table({"k": bk}, jnp.asarray(N, jnp.int32))
+        idx = rel.build_index(build, bk, method="direct", key_range=(1, span))
+        matched, rows = rel.probe_index(idx, pk, jnp.ones(pk.shape, bool))
+        return matched, rows, idx.overflow
+
+    text = _compile_text(join, ((N,), jnp.int64), ((4 * N,), jnp.int64),
+                         sharding=one_chip)
+    assert op_histogram(text, ops=("sort", "while", "gather", "scatter")) \
+        == {"sort": 0, "while": 0, "gather": 1, "scatter": 1}
+
+
 def test_q6_spmd_program_compiles_for_v5e_2x2(topo, compiled_kernels):
     """The four-chip path: run_distributed's program over a 2x2 mesh, with
     ~2^20 lineitem rows per chip, holds its all-reduces and its kernel."""
