@@ -4,7 +4,9 @@ One client sends one request at a time through ``QueryServer.submit`` with
 the defaults users run (exact answers, default planner, sorted joins,
 kernels chosen by the platform) and waits for the NumPy answer before it
 sends the next.  Each request runs inside a host span, ``bench.submit
-q<N>``, which the profiler records when a run is traced.
+q<N>``, which the profiler records when a run is traced.  The server is as
+wide as the cell (``devices=`` its chip count), and set-up refuses a
+program that does not take its inputs on exactly the cell's chips.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ from typing import Iterable, Iterator
 import jax
 from jax import monitoring
 
+from .device import check_spans
 from .traffic import Request
 
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -104,13 +107,16 @@ class Execution:
 
 
 class Client:
-    """One user of a ``QueryServer`` over the generated tables."""
+    """One user of a ``QueryServer`` over the generated tables, served on
+    ``devices``, the cell's chips."""
 
-    def __init__(self, data):
+    def __init__(self, data, devices: list):
         from repro.core.table import Database
         from repro.serve.server import QueryServer
+        self.devices = devices
         self.server = QueryServer(Database(data.tables, data.dicts,
-                                           data.scale))
+                                           data.scale),
+                                  devices=len(devices))
 
     def submit(self, req: Request) -> Execution:
         start = time.perf_counter()
@@ -125,14 +131,16 @@ class Client:
 
     def prepare(self, qids) -> None:
         """Compile (or load) every program the cell runs, one after another
-        in the cell's order.  Programs that call one jitted function share
-        its trace, with the source locations of whichever traced it first;
-        on a TPU those locations are part of each Pallas kernel's payload,
-        and so of the compilation cache's key.  A fixed order gives every
-        process the same keys, so only a checkout's first run compiles."""
+        in the cell's order, and refuse one that does not span the cell's
+        chips (``device.NoChip``).  Programs that call one jitted function
+        share its trace, with the source locations of whichever traced it
+        first; on a TPU those locations are part of each Pallas kernel's
+        payload, and so of the compilation cache's key.  A fixed order gives
+        every process the same keys, so only a checkout's first run
+        compiles."""
         for qid in qids:
             _QUERY.qid = qid
-            self.server.compiled(qid)
+            check_spans(f"q{qid}", self.server.compiled(qid), self.devices)
         _QUERY.qid = None
 
     def counters(self) -> dict[str, int]:

@@ -1,13 +1,18 @@
-"""The chip a run measures, and its peaks.
+"""The chips a run measures, and their peaks.
 
 A run measures a TPU and nothing else: it fails where JAX finds no TPU,
 fewer chips than the cell asks for, or a kind of chip that
-``bench/peaks.json`` does not list.  It never falls back to the CPU.
+``bench/peaks.json`` does not list.  It never falls back to the CPU.  Nor
+does it report a cell's chips for a program that runs on fewer or others:
+every program the cell serves has to take its inputs on exactly the cell's
+chips.
 """
 from __future__ import annotations
 
 import json
 import os
+
+import jax
 
 from .spec import BENCH_DIR
 
@@ -32,6 +37,19 @@ def chips(devices: list, count: int, peaks: dict) -> tuple[list, dict]:
     if kind not in peaks:
         raise NoChip(f"no peaks for device kind {kind!r} in peaks.json")
     return devices[:count], peaks[kind]
+
+
+def check_spans(name: str, program: jax.stages.Compiled,
+                devices: list) -> None:
+    """Raise ``NoChip`` unless the devices of ``program``'s input shardings
+    are exactly ``devices``, the cell's chips."""
+    on = set().union(*(s.device_set
+                       for s in jax.tree.leaves(program.input_shardings)))
+    if on != set(devices):
+        raise NoChip(f"{name} runs on {len(on & set(devices))} of the "
+                     f"cell's {len(devices)} chips"
+                     + (f" and on {len(on - set(devices))} others"
+                        if on - set(devices) else ""))
 
 
 def describe(devices: list) -> dict:

@@ -29,6 +29,7 @@ class Run:
     peaks: dict | None
     column_bytes: dict[str, int]
     query_columns: dict[int, list[str]]
+    chips: int
     trace: tr.Summary | None = None
 
 
@@ -64,7 +65,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     data = datagen.generate(conf["scale_factor"], seed, skew=conf["skew"],
                             hot_share=conf["hot_share"])
     with CompileCounter() as setup:
-        client = Client(data)
+        client = Client(data, devices)
         client.prepare(cell.traffic.queries)
         warm = client.run_pass(next(cell.traffic.passes(seed, WARM_STREAM)))
     for ex in warm:
@@ -119,7 +120,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         query_columns = {int(q): v["columns"]
                          for q, v in json.load(f)["queries"].items()}
     run = Run(executions, window_s, setup_s, peaks,
-              data.column_bytes(), query_columns, summary)
+              data.column_bytes(), query_columns, len(devices), summary)
     metrics = {}
     for m in cell.metrics:
         if (m.kind == "per_layer") != traced:

@@ -1,29 +1,51 @@
-"""Profiler trace -> device time by class of operation, idle gaps, spans.
+"""Profiler trace -> device time by class of operation and by operator
+scope, idle gaps, spans.
 
 A traced run profiles one pass.  The reduction reads the ``.xplane.pb`` the
 profiler writes, with nothing but JAX:
 
-* device operations: the events of each TPU plane's ``XLA Ops`` line;
+* device operations: the events of every TPU plane's ``XLA Ops`` line, one
+  plane per chip, each charged to the request whose program execution (an
+  ``XLA Modules`` event of its chip) holds it;
 * host spans: the benchmark's ``bench.submit q<N>`` annotations around each
-  request, on the same clock.
+  request, on the same clock as far as the trace aligns it.
 
 Each device operation is classed by HLO opcode, taken from the optimized HLO
 text of the program the request ran (``QueryServer.compiled``), never from
-its name: a fusion takes the classes of the instructions it fuses.  Classes:
-``kernel`` (a Pallas ``tpu_custom_call``), ``sort``, ``gather_scatter``,
-``other``; an operation that holds several counts under the first of that
-order.
+its name: a fusion, or an async wrapper, takes the classes of the
+instructions it calls.  Classes: ``kernel`` (a Pallas ``tpu_custom_call``),
+``sort``, ``collective`` (an exchange between chips: ``all-to-all``,
+``all-gather``, ``all-reduce``, ``reduce-scatter``, ``collective-permute``,
+or their ``-start``/``-done`` halves), ``gather_scatter``, ``other``; an
+operation that holds several counts under the first of that order.
+
+Each operation also gets the engine's operator scope (``Op.scope``): the
+innermost ``rel.*`` component of the ``op_name`` metadata its HLO
+instruction carries (``repro.core.tracing`` wraps each relational operator
+in a ``jax.named_scope``), None outside every scope; a fusion without
+metadata takes its fused root's.  Every operation has one scope or none, so
+the scopes' device times and the unscoped time add up to the pass's.
+
+Times of several chips add up: ``class_s`` and ``scope_s`` sum device
+seconds over the chips, ``busy_s`` and ``busy_within`` average them.
 """
 from __future__ import annotations
 
+import bisect
 import contextlib
 import dataclasses
 import glob
 import os
 import re
 
-CLASSES = ("kernel", "sort", "gather_scatter", "other")
+CLASSES = ("kernel", "sort", "collective", "gather_scatter", "other")
+COLLECTIVES = frozenset(
+    f"{op}{half}" for op in ("all-to-all", "all-gather", "all-reduce",
+                             "reduce-scatter", "collective-permute")
+    for half in ("", "-start", "-done"))
 SPAN = re.compile(r"^bench\.submit q(\d+)$")
+OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+SCOPE = re.compile(r"(?:^|/)(rel\.[A-Za-z_]+)(?=/|$)")
 _COMP = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
@@ -31,25 +53,40 @@ _CALLS = re.compile(r"(?:calls|to_apply|body|condition|branch_computations)"
                     r"=\{?%?([\w.\-]+)")
 
 
-def hlo_classes(text: str) -> dict[str, str]:
-    """Instruction name -> class, for every instruction of an HLO module."""
-    comps: dict[str, list[tuple[str, str, list[str]]]] = {}
+# instructions whose class and scope come from the computations they call
+_CALLERS = frozenset({"fusion", "async-start", "async-update", "async-done"})
+
+
+def _instructions(text: str):
+    """(computation, instruction name, the rest of its line, whether it is
+    the computation's ROOT) for every instruction of an HLO module."""
     current = None
     for line in text.splitlines():
         m = _INSTR.match(line)
         if m and current is not None:
-            name, rest = m.groups()
-            op = _OPCODE.search(rest)
-            opcode = op.group(1) if op else ""
-            if opcode == "custom-call" and "tpu_custom_call" in rest:
-                opcode = "tpu_custom_call"
-            called = _CALLS.findall(rest) if opcode == "fusion" else []
-            comps[current].append((name, opcode, called))
+            yield (current, m.group(1), m.group(2),
+                   line.lstrip().startswith("ROOT"))
             continue
         m = _COMP.match(line)
         if m and "=" not in line.split("{")[0]:
             current = m.group(1)
-            comps[current] = []
+
+
+def _opcode(rest: str) -> str:
+    op = _OPCODE.search(rest)
+    opcode = op.group(1) if op else ""
+    if opcode == "custom-call" and "tpu_custom_call" in rest:
+        return "tpu_custom_call"
+    return opcode
+
+
+def hlo_classes(text: str) -> dict[str, str]:
+    """Instruction name -> class, for every instruction of an HLO module."""
+    comps: dict[str, list[tuple[str, str, list[str]]]] = {}
+    for comp, name, rest, _ in _instructions(text):
+        opcode = _opcode(rest)
+        called = _CALLS.findall(rest) if opcode in _CALLERS else []
+        comps.setdefault(comp, []).append((name, opcode, called))
 
     def opcodes(comp: str, seen: set) -> set[str]:
         out = set()
@@ -76,9 +113,42 @@ def classify(opcodes: set[str]) -> str:
         return "kernel"
     if "sort" in opcodes:
         return "sort"
+    if opcodes & COLLECTIVES:
+        return "collective"
     if opcodes & {"gather", "scatter"}:
         return "gather_scatter"
     return "other"
+
+
+def scope_of(op_name: str) -> str | None:
+    """The innermost ``rel.*`` component of an ``op_name``."""
+    found = SCOPE.findall(op_name)
+    return found[-1] if found else None
+
+
+def hlo_scopes(text: str) -> dict[str, str | None]:
+    """Instruction name -> operator scope, for every instruction of an HLO
+    module."""
+    own: dict[str, str | None] = {}     # None: the instruction has no op_name
+    calls: dict[str, list[str]] = {}
+    roots: dict[str, str] = {}          # computation -> its ROOT instruction
+    for comp, name, rest, root in _instructions(text):
+        meta = OP_NAME.search(rest)
+        own[name] = meta.group(1) if meta else None
+        if _opcode(rest) in _CALLERS:
+            calls[name] = _CALLS.findall(rest)
+        if root:
+            roots[comp] = name
+
+    def scope(name: str) -> str | None:
+        if own.get(name) is not None:
+            return scope_of(own[name])
+        for comp in calls.get(name, [])[:1]:   # a fusion: its fused root's
+            if comp in roots:
+                return scope(roots[comp])
+        return None
+
+    return {name: scope(name) for name in own}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,6 +162,7 @@ class Op:
     end_ns: float
     device: str
     self_ns: float | None = None
+    scope: str | None = None
 
     @property
     def dur_ns(self) -> float:
@@ -174,6 +245,11 @@ class Summary:
         """Device seconds of one class of operation, summed over devices."""
         return sum(o.dur_ns for o in self.ops if o.cls == cls) / 1e9
 
+    def scope_s(self, scope: str | None) -> float:
+        """Device seconds (self time) of the operations in ``scope``, summed
+        over devices; ``None`` gives the operations outside every scope."""
+        return sum(o.dur_ns for o in self.ops if o.scope == scope) / 1e9
+
     def busy_within(self, span: Span) -> float:
         """Device-busy seconds inside one host span, averaged over devices."""
         devs = self.devices
@@ -206,9 +282,13 @@ class Summary:
         return sorted((g for g in gaps if g[1] > 0), key=lambda g: -g[1])
 
     def breakdown(self) -> dict:
+        """The ten device operations that took most time, each keyed with
+        its scope (``q3 rel.join_probe fusion.99 (gather_scatter)``), and the
+        ten longest idle gaps."""
         by_op: dict[str, float] = {}
         for o in self.ops:
-            key = f"q{o.qid} {o.name} ({o.cls})"
+            key = " ".join(x for x in (f"q{o.qid}", o.scope, o.name) if x) \
+                + f" ({o.cls})"
             by_op[key] = by_op.get(key, 0.0) + o.dur_ns / 1e9
         top = sorted(by_op.items(), key=lambda kv: -kv[1])[:10]
         return {"device_ops": [[k, v] for k, v in top],
@@ -261,11 +341,32 @@ def _offset(spans: list[Span], modules: list[tuple[float, float]]) -> float:
     return max(lo, min(0.0, hi)) if lo <= hi else 0.0
 
 
+def _owners(spans: list[Span], modules: list[tuple[float, float]],
+            shift: float) -> list[Span | None]:
+    """The request span each program execution ran in: the one it overlaps
+    most once the clocks are aligned (None where it overlaps none)."""
+    out = []
+    for a, b in modules:
+        a, b = a - shift, b - shift
+        best, most = None, 0.0
+        for s in spans:
+            overlap = min(b, s.end_ns) - max(a, s.start_ns)
+            if overlap > most:
+                best, most = s, overlap
+        out.append(best)
+    return out
+
+
 def reduce(profile, hlo: dict[int, str]) -> Summary:
+    """Each device operation is charged to the request whose program
+    execution (``XLA Modules`` event, on the operation's own chip and clock)
+    holds it; an operation outside every execution, to the request span
+    that holds its start."""
     classes = {q: hlo_classes(text) for q, text in hlo.items()}
+    scopes = {q: hlo_scopes(text) for q, text in hlo.items()}
     spans = []
     raw = []
-    modules = []
+    modules: dict[str, list[tuple[float, float]]] = {}
     for plane in profile.planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -277,8 +378,8 @@ def reduce(profile, hlo: dict[int, str]) -> Summary:
         elif re.match(r"^/device:TPU:\d+$", plane.name):
             for line in plane.lines:
                 if line.name == "XLA Modules":
-                    modules += [(ev.start_ns, ev.end_ns)
-                                for ev in line.events]
+                    modules[plane.name] = sorted(
+                        (ev.start_ns, ev.end_ns) for ev in line.events)
                 if line.name == "XLA Ops":
                     evs = [(ev.name, ev.start_ns, ev.end_ns)
                            for ev in line.events]
@@ -286,18 +387,27 @@ def reduce(profile, hlo: dict[int, str]) -> Summary:
                     raw += [(n, a, b, plane.name, o)
                             for (n, a, b), o in zip(evs, own)]
     spans.sort(key=lambda s: s.start_ns)
-    shift = _offset(spans, modules) if spans else 0.0
+    everything = [m for ms in modules.values() for m in ms]
+    shift = _offset(spans, everything) if spans else 0.0
+    owners = {dev: _owners(spans, ms, shift) for dev, ms in modules.items()}
+    starts = {dev: [a for a, _ in ms] for dev, ms in modules.items()}
     ops = []
     for text, a, b, dev, own in raw:
+        i = bisect.bisect_right(starts.get(dev, []), a) - 1
+        owner = owners[dev][i] if i >= 0 and a <= modules[dev][i][1] \
+            else None
         a, b = a - shift, b - shift
+        if owner is None:
+            owner = next((s for s in spans if s.start_ns <= a <= s.end_ns),
+                         None)
+        qid = owner.qid if owner else None
         # an event is named by its HLO instruction: "%name = shape op(...)"
         m = _INSTR.match(text)
         name = m.group(1) if m else text
-        owner = [s for s in spans if s.start_ns <= a <= s.end_ns]
-        qid = owner[0].qid if owner else None
         cls = classes.get(qid, {}).get(name)
         if cls is None:           # not in the program's HLO: its own opcode
             op = _OPCODE.search(m.group(2)) if m else None
             cls = classify({op.group(1)} if op else set())
-        ops.append(Op(name, qid, cls, a, b, dev, own))
+        ops.append(Op(name, qid, cls, a, b, dev, own,
+                      scopes.get(qid, {}).get(name)))
     return Summary(ops, spans)

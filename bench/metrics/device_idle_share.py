@@ -1,5 +1,6 @@
 """Share of the traced pass in which no operation ran on the device: 1 less
-the union of the device operations' intervals over the pass."""
+the union of the device operations' intervals over the pass, averaged over
+the chips."""
 LAYER, UNIT, MOVES = "device (TPU v5e)", "%", "query_geomean_ms"
 
 

@@ -1,5 +1,5 @@
 """Device time of HLO ``gather`` and ``scatter`` operations (and of fusions
-holding one) in the traced pass."""
+holding one) in the traced pass, summed over the chips."""
 LAYER, UNIT, MOVES = "relational ops (core/relational.py)", "ms", "pass_s"
 
 

@@ -1,7 +1,8 @@
 """The traced pass's share of the HBM roofline: the bytes of the TPC-H
 columns each executed query must read at least once (``querybytes.json``,
-from the spec's SQL, not from the program's plan), at the chip's peak HBM
-bandwidth (``peaks.json``), over the traced pass's length."""
+from the spec's SQL, not from the program's plan), at the peak HBM
+bandwidth (``peaks.json``) of the cell's chips together, over the traced
+pass's length."""
 LAYER, UNIT, MOVES = "device (TPU v5e)", "%", "pass_s"
 
 
@@ -11,4 +12,5 @@ def read(run):
         return None
     need = sum(run.column_bytes[c] for ex in run.executions
                for c in run.query_columns[ex.qid])
-    return 100.0 * need / run.peaks["hbm_bytes_per_s"] / t.window_s
+    return 100.0 * need / (run.chips * run.peaks["hbm_bytes_per_s"]) \
+        / t.window_s

@@ -1,0 +1,12 @@
+"""Device self time of the ``rel.join_build`` operator scope (``build_index``:
+the direct-address scatter, the build-side argsort or the hash buckets) in
+the traced pass, summed over the chips."""
+LAYER, UNIT, MOVES = "relational ops (core/relational.py)", "ms", "pass_s"
+SCOPE = "rel.join_build"
+
+
+def read(run):
+    t = run.trace
+    if t is None or not any(o.scope == SCOPE for o in t.ops):
+        return None
+    return 1e3 * t.scope_s(SCOPE)

@@ -1,5 +1,5 @@
 """Device time of the Pallas kernels (``tpu_custom_call``) in the traced
-pass."""
+pass, summed over the chips."""
 LAYER, UNIT, MOVES = "kernels (kernels/*)", "ms", "query_geomean_ms"
 
 

@@ -1,5 +1,6 @@
-"""The engine's own tracing in a trace: operator scopes from HLO metadata,
-``serve.*`` host spans, and a small trace recorded on a v5e chip."""
+"""The engine's own tracing in a trace: operator scopes from HLO metadata
+(each device operation's ``Op.scope``), ``serve.*`` host spans, and a small
+trace recorded on a v5e chip."""
 import os
 
 import pytest
@@ -43,7 +44,7 @@ ENTRY %main.9 (p: s64[8], i: s32[4]) -> (s64[8], s64[4]) {
 
 
 def test_scope_is_the_innermost_rel_component():
-    s = sc.hlo_scopes(HLO)
+    s = tr.hlo_scopes(HLO)
     assert s["fusion.2"] == "rel.join_probe"
     assert s["sort.5"] == "rel.compact"          # a compact inside a group-by
     assert s["custom-call.6"] == "rel.group_by"
@@ -55,10 +56,8 @@ def test_scope_is_the_innermost_rel_component():
 def test_the_names_read_are_the_engines():
     """A rename in the engine cannot silently blank a reading."""
     from repro.core import tracing
-    read = [s for s in sc.METRIC_SCOPES.values() if s is not None]
-    assert set(read) <= set(tracing.SCOPES)
     assert set(sc.LAUNCH + sc.FETCH) <= set(tracing.SPANS)
-    assert all(sc.SCOPE.fullmatch(s) for s in tracing.SCOPES)
+    assert all(tr.SCOPE.fullmatch(s) for s in tracing.SCOPES)
     assert all(s.startswith(sc.HOST_SPAN) for s in tracing.SPANS)
 
 
@@ -94,18 +93,19 @@ planes { id: 2 name: "/host:CPU"
 def scoped():
     from jax.profiler import ProfileData
     profile = ProfileData.from_text_proto(XSPACE)
-    return sc.Scoped.of(tr.reduce(profile, {3: HLO}), {3: HLO}, profile)
+    return sc.Scoped.of(tr.reduce(profile, {3: HLO}), profile)
 
 
 def test_scoped_device_time_adds_up_to_the_pass():
-    s = scoped()
-    assert s.scopes == ["rel.compact", "rel.join_take", None]
+    s = scoped().summary
+    assert [o.scope for o in s.ops] == ["rel.compact", "rel.join_take", None]
     assert s.scope_s("rel.compact") == pytest.approx(3e-6)
     assert s.scope_s("rel.join_take") == pytest.approx(1e-6)
     assert s.scope_s(None) == pytest.approx(1e-6)
     assert s.scope_s("rel.join_build") == 0.0
-    total = sum(o.dur_ns for o in s.summary.ops) / 1e9
-    assert sum(s.scope_s(x) for x in set(s.scopes)) == pytest.approx(total)
+    total = sum(o.dur_ns for o in s.ops) / 1e9
+    assert sum(s.scope_s(x) for x in {o.scope for o in s.ops}) == \
+        pytest.approx(total)
     assert s.breakdown()["device_ops"][0] == [
         "q3 rel.compact sort.5 (sort)", pytest.approx(3e-6)]
 
@@ -139,12 +139,13 @@ def test_a_recorded_v5e_trace_reduces_with_scopes_and_spans():
         hlo = f.read()
     with gzip.open(os.path.join(DATA, "v5e_scoped.xplane.pb.gz")) as f:
         profile = ProfileData.from_serialized_xspace(f.read())
-    s = sc.Scoped.of(tr.reduce(profile, {12: hlo}), {12: hlo}, profile)
+    s = sc.Scoped.of(tr.reduce(profile, {12: hlo}), profile)
     assert len(s.summary.ops) == 192
     assert all(o.qid == 12 for o in s.summary.ops)
+    scopes = {o.scope for o in s.summary.ops}
     assert {"rel.join_build", "rel.join_probe", "rel.join_take",
-            "rel.group_by"} <= set(s.scopes)
-    assert s.scope_s("rel.join_probe") > s.scope_s(None)
+            "rel.group_by"} <= scopes
+    assert s.summary.scope_s("rel.join_probe") > s.summary.scope_s(None)
     (req,) = s.summary.spans
     assert [(h.name, h.args) for h in s.host] == [
         ("serve.submit", {"request": 2, "template": "q12"}),
@@ -155,4 +156,4 @@ def test_a_recorded_v5e_trace_reduces_with_scopes_and_spans():
     labels = {label for label, _ in s.idle_gaps()}
     assert {"q12 serve.wait", "q12 serve.fetch"} <= labels
     total = sum(o.dur_ns for o in s.summary.ops) / 1e9
-    assert sum(s.scope_s(x) for x in set(s.scopes)) == pytest.approx(total)
+    assert sum(s.summary.scope_s(x) for x in scopes) == pytest.approx(total)

@@ -71,6 +71,17 @@ def test_a_cell_and_a_metric_added_as_files_are_found(checkout):
     assert "answers_n" not in {m.name for m in other.metrics}
 
 
+def test_sort_ms_is_read_in_the_join_cell_only():
+    bench = Bench(ROOT)
+    join = {m.name for m in bench.cell("tpch_sf1.join").metrics}
+    scan = {m.name for m in bench.cell("tpch_sf1.scan_agg").metrics}
+    assert "sort_ms" in join and "sort_ms" not in scan
+    assert "compact_ms" in join and "compact_ms" not in scan
+    for name in ("group_by_ms", "join_probe_ms", "join_take_ms",
+                 "join_build_ms", "order_ms", "unscoped_ms"):
+        assert name in join and name in scan
+
+
 def test_an_unknown_cell_is_an_error():
     with pytest.raises(KeyError, match="no workload"):
         Bench(ROOT).cell("nope.none")
