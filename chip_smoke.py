@@ -6,11 +6,11 @@ first chip, and check every answer against the NumPy reference
 (``RefContext``) at rtol 1e-7.  Then serve each template again and check
 that nothing recompiled.
 
-``--chips 4``: run the 22 queries SPMD through ``QueryRunner`` on a
-four-chip mesh at ``--sf`` (4), tables hash-partitioned over the chips
-(paper §4.3).  Checks the answers, each query's shuffle/broadcast counts
-against the plan's static (paper Table 4) counts, and that every partitioned
-input sits on all four chips.
+``--chips 4``: the same through ``QueryServer(db, devices=4)`` at ``--sf``
+(4): tables hash-partitioned over a four-chip mesh (paper §4.3), one SPMD
+program per template.  Also checks that every program takes its inputs on
+the four chips, and each template's exchanges against the plan's static
+(paper Table 4) counts.
 
 Per-query lines are timings of this one run, not a benchmark: compile
 seconds (the 22 programs compile at once on a thread pool, so each number
@@ -39,6 +39,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
 RTOL = 1e-7
+# a plan's static exchange counts -> QueryServer.exchanges' kinds
+_KIND = {"shuffles": "shuffle", "broadcasts": "broadcast",
+         "final_gathers": "gather", "allreduces": "allreduce"}
 
 
 def compare(got: dict, want: dict, label: str) -> None:
@@ -82,15 +85,16 @@ def _timed(fn, *args):
     return time.perf_counter() - t0, out
 
 
-def serve_phase(sf: float, seed: int) -> list[str]:
-    """All 22 templates through ``QueryServer`` on one chip; failures."""
+def serve_phase(sf: float, seed: int, chips: int) -> list[str]:
+    """All 22 templates through ``QueryServer`` on ``chips``; failures."""
+    import jax
     from repro.core import backend as B
     from repro.serve.server import QueryServer
     from repro.serve.templates import TEMPLATES
 
-    device = tpu_devices(1)[0]
+    devices = tpu_devices(chips)
     db = generate(sf, seed)
-    server = QueryServer(db)
+    server = QueryServer(db, devices=chips)
     templates = dict(sorted(TEMPLATES.items()))
     # XLA releases the GIL while it compiles, and a v5e program with large
     # sorts takes tens of seconds to compile: compile all 22 at once, and
@@ -122,6 +126,16 @@ def serve_phase(sf: float, seed: int) -> list[str]:
                   f" match", flush=True)
             if attempts != 1:
                 failed.append(f"{label}: {attempts} attempts")
+            on = set().union(*(sh.device_set for sh in
+                               jax.tree.leaves(exe.input_shardings)))
+            if on != set(devices):
+                failed.append(f"{label}: runs on {len(on)} chips")
+            if chips > 1:
+                got = server.exchanges(template)
+                static = template.query.static_counts()
+                print(f"{label} exchanges={got} static={static}", flush=True)
+                if any(got[_KIND[k]] != v for k, v in static.items()):
+                    failed.append(f"{label}: exchanges {got} != {static}")
         except Exception as e:  # report every query, then fail the run
             print(f"{label} FAILED {type(e).__name__}: {e}", flush=True)
             failed.append(label)
@@ -135,76 +149,9 @@ def serve_phase(sf: float, seed: int) -> list[str]:
           flush=True)
     if server.recompiles != recompiles:
         failed.append("recompiled on the second pass")
-    stats = device.memory_stats() or {}
-    print(f"# peak_bytes_in_use={stats.get('peak_bytes_in_use')}", flush=True)
-    return failed
-
-
-def mesh_phase(sf: float, seed: int, chips: int) -> list[str]:
-    """All 22 queries SPMD through ``QueryRunner`` on ``chips``; failures."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core import backend as B
-    from repro.core.compat import make_mesh
-    from repro.distributed.fault import QueryRunner
-    from repro.queries import QUERIES
-
-    devices = tpu_devices(chips)
-    mesh = make_mesh((chips,), ("data",), devices=devices)
-    db = generate(sf, seed)
-    failed = []
-    sharded, _ = B.partition_database(db, chips)
-    for name, cols in B.place_partitions(sharded, mesh).items():
-        for col, arr in cols.items():
-            on = {s.device for s in arr.addressable_shards}
-            if on != set(devices) or arr.sharding.device_set != set(devices):
-                failed.append(f"{name}.{col} sits on {sorted(map(str, on))}")
-    print(f"# partitioned inputs on {chips} devices: "
-          f"{'yes' if not failed else 'NO'}", flush=True)
-    # QueryRunner builds a fresh program per call: compile all 22 at once
-    # into the persistent compilation cache, which its compiles then read
-    spec = NamedSharding(mesh, P("data"))
-    shapes = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=spec),
-        sharded)
-    queries = dict(sorted(QUERIES.items()))
-    threads = min(len(queries), os.cpu_count() or 1)
-    with ThreadPoolExecutor(threads) as pool:
-        t0 = time.perf_counter()
-        compiling = {
-            qid: pool.submit(_timed, lambda q: B.distributed_program(
-                q, db, mesh)[0].lower(shapes).compile(), q)
-            for qid, q in queries.items()}
-        want = {qid: pool.submit(B.run_reference, q, db)
-                for qid, q in queries.items()}
-        wait(compiling.values())
-    print(f"# compiled {len(queries)} programs in "
-          f"{time.perf_counter() - t0:.1f} s on {threads} threads", flush=True)
-    runner = QueryRunner(db, mesh)
-    for qid, query in queries.items():
-        label = f"q{qid:02d}"
-        try:
-            compile_s, exe = compiling[qid].result()
-            res = runner.run(query)
-            compare(res.result, want[qid].result()[0], label)
-            counts, static = res.stats.counts(), query.static_counts()
-            print(f"{label} compile_s={compile_s:.1f} "
-                  f"run_ms={res.wall_s * 1e3:.1f} "
-                  f"rows={len(next(iter(res.result.values())))} "
-                  f"attempts={res.attempts} shuffles={counts['shuffles']} "
-                  f"broadcasts={counts['broadcasts']} "
-                  f"tpu_custom_calls={exe.as_text().count('tpu_custom_call')}"
-                  f" match", flush=True)
-            if counts != static:
-                failed.append(f"{label}: counts {counts} != static {static}")
-            if res.attempts != 1:
-                failed.append(f"{label}: {res.attempts} attempts")
-        except Exception as e:  # report every query, then fail the run
-            print(f"{label} FAILED {type(e).__name__}: {e}", flush=True)
-            failed.append(label)
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
              for d in devices]
-    print(f"# peak_bytes_in_use per device={peaks}", flush=True)
+    print(f"# peak_bytes_in_use per chip={peaks}", flush=True)
     return failed
 
 
@@ -223,10 +170,7 @@ def main() -> None:
     print(f"# device platform={device.platform} kind={device.device_kind} "
           f"count={len(jax.devices())}  compile cache={use_compile_cache()}",
           flush=True)
-    if args.chips == 1:
-        failed = serve_phase(args.sf or 1.0, args.seed)
-    else:
-        failed = mesh_phase(args.sf or 4.0, args.seed, args.chips)
+    failed = serve_phase(args.sf or float(args.chips), args.seed, args.chips)
     if failed:
         sys.exit(f"chip_smoke: failed: {', '.join(failed)}")
     print(json.dumps({"ok": True, "device": {

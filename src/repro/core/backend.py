@@ -788,6 +788,16 @@ PARTITION_KEYS = {
 }
 
 
+def shard_capacity(nrows: int, n: int) -> int:
+    """Rows a hash partition of an ``nrows`` table over ``n`` shards has room
+    for: the mean share plus eight standard deviations of it.  Rows come in
+    groups of up to 7 per key (lineitem's lines of an order), which
+    multiplies a binomial share's variance by at most 7 (8 here).  A shard
+    that holds more anyway gets its own size (``partition_database``)."""
+    mean = nrows / n
+    return int(math.ceil(mean + 8.0 * math.sqrt(8.0 * mean)))
+
+
 def partition_database(db: Database, n: int,
                        partition_keys: dict | None = None,
                        ) -> tuple[dict[str, dict], dict[str, int]]:
@@ -795,7 +805,11 @@ def partition_database(db: Database, n: int,
 
     Returns columns shaped (n*cap,) and counts shaped (n,) ready for shard_map
     with in_specs=P(axis).  Replicated tables (key None) appear whole in every
-    shard — the standard treatment for tiny dimension tables.
+    shard — the standard treatment for tiny dimension tables.  Rows keep
+    their table order within a shard.  A shard's capacity follows from the
+    table's row count alone (``shard_capacity``), so tables of one size
+    partition to the same shapes, and lower to the same programs, whatever
+    their keys.
     """
     pk = dict(PARTITION_KEYS)
     if partition_keys:
@@ -805,20 +819,23 @@ def partition_database(db: Database, n: int,
         nrows = len(next(iter(t.values())))
         key = pk.get(name)
         if key is None:
-            shards = [t] * n
+            counts = np.full(n, nrows)
+            rows = [np.arange(nrows)] * n
         else:
             dest = hash_partition_np(np.asarray(t[key]), n)
-            shards = [{k: v[dest == d] for k, v in t.items()} for d in range(n)]
-        cap = max(8, int(math.ceil(max(len(next(iter(s.values()))) for s in shards)
-                                   / 8)) * 8)
+            counts = np.bincount(dest, minlength=n)
+            # a stable sort of 16-bit destinations is a radix sort
+            order = np.argsort(dest.astype(np.int16), kind="stable")
+            rows = np.split(order, np.cumsum(counts)[:-1])
+        cap = nrows if key is None else shard_capacity(nrows, n)
+        cap = max(8, int(math.ceil(max(cap, counts.max()) / 8)) * 8)
         cols = {}
-        for cname in t:
-            stacked = np.zeros((n * cap,), dtype=t[cname].dtype)
-            for d, s in enumerate(shards):
-                stacked[d * cap: d * cap + len(s[cname])] = s[cname]
+        for cname, v in t.items():
+            stacked = np.zeros((n * cap,), dtype=v.dtype)
+            for d, r in enumerate(rows):
+                np.take(v, r, out=stacked[d * cap: d * cap + len(r)])
             cols[cname] = stacked
-        cols["__count"] = np.array(
-            [len(next(iter(s.values()))) for s in shards], dtype=np.int32)
+        cols["__count"] = counts.astype(np.int32)
         out[name] = cols
         caps[name] = cap
     return out, caps
@@ -833,29 +850,31 @@ def place_partitions(sharded: dict[str, dict], mesh: Mesh,
             for name, cols in sharded.items()}
 
 
-def distributed_program(query_fn, db: Database, mesh: Mesh,
-                        axis: str = "data", capacity_factor: float = 2.0,
-                        packed_exchange: bool = True,
-                        join_method: str = "sorted",
-                        use_kernel: bool | None = None,
-                        wire_format: str | None = None, chaos=None):
-    """The jitted SPMD program ``run_distributed`` calls on the
-    ``place_partitions`` inputs, and the dict its trace fills with the plan
-    statistics (``"stats"``)."""
-    n = mesh.shape[axis]
-    holder = {}
+def spmd_program(body: Callable, db: Database, mesh: Mesh,
+                 axis: str = "data", capacity_factor: float = 2.0,
+                 packed_exchange: bool = True, join_method: str = "sorted",
+                 use_kernel: bool | None = None,
+                 wire_format: str | None = None, chaos=None):
+    """The one SPMD program builder: ``jax.jit(shard_map(...))`` of
+    ``body(ctx, params)``, where every device runs ``body`` in a
+    :class:`DistContext` over its partition of the ``place_partitions``
+    tables and ``params`` (a pytree of scalars) enters replicated.
 
-    def spmd(tree):
-        tables = {}
-        for name, cols in tree.items():
-            cnt = cols.pop("__count").reshape(())
-            tables[name] = Table(cols, cnt)
+    The program is ``fn(tables, params) -> (result, overflow, corrupt)``,
+    each with one entry per device along ``axis``; every device holds the
+    whole (finalized) result, so ``first_shard`` reads it."""
+    n = mesh.shape[axis]
+
+    def spmd(tree, params):
+        tables = {name: Table({k: v for k, v in cols.items()
+                               if k != "__count"},
+                              cols["__count"].reshape(()))
+                  for name, cols in tree.items()}
         ctx = DistContext(db, tables, axis, n, capacity_factor,
                           packed_exchange, join_method, use_kernel,
                           wire_format)
         ctx.chaos = chaos
-        out = query_fn(ctx)
-        holder["stats"] = ctx.stats
+        out = body(ctx, params)
         if isinstance(out, dict):
             out = Table({k: jnp.asarray(v).reshape(1) for k, v in out.items()},
                         jnp.asarray(1, jnp.int32))
@@ -863,8 +882,17 @@ def distributed_program(query_fn, db: Database, mesh: Mesh,
         return (Table(dict(out.columns), out.count.reshape(1)),
                 ctx.overflow.reshape(1), ctx.corrupt.reshape(1))
 
-    return jax.jit(compat.shard_map(spmd, mesh=mesh, in_specs=P(axis),
-                                    out_specs=P(axis))), holder
+    return jax.jit(compat.shard_map(spmd, mesh=mesh, in_specs=(P(axis), P()),
+                                    out_specs=P(axis)))
+
+
+def first_shard(out: Table) -> Table:
+    """The first device's copy of an ``spmd_program`` result: a Table of
+    device arrays that ``to_numpy`` copies without another program."""
+    def first(a):
+        return a.addressable_shards[0].data
+    return Table({k: first(v) for k, v in out.columns.items()},
+                 np.asarray(first(out.count))[0])
 
 
 def run_distributed(query_fn, db: Database, mesh: Mesh, axis: str = "data",
@@ -885,14 +913,18 @@ def run_distributed(query_fn, db: Database, mesh: Mesh, axis: str = "data",
     """
     n = mesh.shape[axis]
     sharded, _ = partition_database(db, n, partition_keys)
-    fn, holder = distributed_program(query_fn, db, mesh, axis,
-                                     capacity_factor, packed_exchange,
-                                     join_method, use_kernel, wire_format,
-                                     chaos)
-    out, overflow, corrupt = fn(place_partitions(sharded, mesh, axis))
+    holder = {}
+
+    def body(ctx, _params):
+        out = query_fn(ctx)
+        holder["stats"] = ctx.stats
+        return out
+
+    fn = spmd_program(body, db, mesh, axis, capacity_factor, packed_exchange,
+                      join_method, use_kernel, wire_format, chaos)
+    out, overflow, corrupt = fn(place_partitions(sharded, mesh, axis), {})
     if bool(np.any(np.asarray(corrupt))):
         raise wi.CorruptPayload(
             "distributed run: payload integrity check failed")
-    result = Table({k: v[: v.shape[0] // n] for k, v in out.columns.items()},
-                   out.count[0])
-    return to_numpy(result), holder["stats"], bool(np.any(np.asarray(overflow)))
+    return (to_numpy(first_shard(out)), holder["stats"],
+            bool(np.any(np.asarray(overflow))))

@@ -64,6 +64,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import tracing
 from . import wire as wi
 from .table import Table
 from .relational import agg_kernel_default, ensure_compact, hash_partition_ids
@@ -167,6 +168,7 @@ def _dispatch_offsets(dest: jax.Array, num_partitions: int,
     return slot, counts[:num_partitions]
 
 
+@tracing.op_scope(tracing.EXCHANGE)
 def shuffle(t: Table, key: jax.Array, axis_name: str, num_partitions: int,
             cap_per_dest: int, packed: bool = True,
             dest_ids: jax.Array | None = None,
@@ -285,6 +287,7 @@ def shuffle(t: Table, key: jax.Array, axis_name: str, num_partitions: int,
 # broadcast
 # ---------------------------------------------------------------------------
 
+@tracing.op_scope(tracing.EXCHANGE)
 def broadcast_table(t: Table, axis_name: str, num_partitions: int,
                     packed: bool = True, wire: Mapping | None = None,
                     narrow: bool | None = None, tamper=None,
@@ -353,6 +356,7 @@ def broadcast_table(t: Table, axis_name: str, num_partitions: int,
     return out, overflow, corrupt, stats
 
 
+@tracing.op_scope(tracing.EXCHANGE)
 def broadcast_table_p2p(t: Table, axis_name: str, num_partitions: int,
                         ) -> tuple[Table, ExchangeStats]:
     """§7.1 baseline: emulate broadcast with N-1 p2p ring forwards of the FULL
@@ -394,6 +398,7 @@ def broadcast_table_p2p(t: Table, axis_name: str, num_partitions: int,
 # reductions
 # ---------------------------------------------------------------------------
 
+@tracing.op_scope(tracing.EXCHANGE)
 def partial_to_global(partials: dict[str, jax.Array], ops: dict[str, str],
                       axis_name: str) -> dict[str, jax.Array]:
     """ncclAllReduce equivalent for final scalar aggregation.

@@ -26,27 +26,27 @@ import time
 
 import jax
 
-# operator scopes (core/relational.py)
-JOIN_BUILD = "rel.join_build"    # build_index: the direct-address scatter,
-                                 # the build-side argsort or the buckets
-JOIN_PROBE = "rel.join_probe"    # probe_index: the direct-address gather,
-                                 # searchsorted or the hash probe
+# operator scopes (core/relational.py, core/exchange.py)
+JOIN_BUILD = "rel.join_build"    # build_index: direct scatter, argsort, buckets
+JOIN_PROBE = "rel.join_probe"    # probe_index: direct gather, searchsorted, hash
 JOIN_TAKE = "rel.join_take"      # gathers of build columns through the index
 GROUP_BY = "rel.group_by"        # group_aggregate, every path
 COMPACT = "rel.compact"          # front compaction
 ORDER = "rel.order"              # sort_by, sort_limit
-SCOPES = (JOIN_BUILD, JOIN_PROBE, JOIN_TAKE, GROUP_BY, COMPACT, ORDER)
+EXCHANGE = "rel.exchange"        # shuffles, broadcasts, gathers, all-reduces
+SCOPES = (JOIN_BUILD, JOIN_PROBE, JOIN_TAKE, GROUP_BY, COMPACT, ORDER,
+          EXCHANGE)
 
-# host spans (serve/server.py), all inside SUBMIT
+# host spans (serve/server.py), all but PLACE inside SUBMIT
+PLACE = "serve.place"            # partition the tables, put them on the mesh
 SUBMIT = "serve.submit"          # one request; args request=<n>, template=
 BIND = "serve.bind"              # bind the parameters, put them on device
 LOOKUP = "serve.lookup"          # find (or build) the executable
-DISPATCH = "serve.dispatch"      # enqueue the program (trace and compile
-                                 # on a miss)
+DISPATCH = "serve.dispatch"      # enqueue the program (compile on a miss)
 WAIT = "serve.wait"              # block on the overflow flag: the device
 RERUN = "serve.rerun"            # the conservative rerun after an overflow
 FETCH = "serve.fetch"            # copy the result to the host
-SPANS = (SUBMIT, BIND, LOOKUP, DISPATCH, WAIT, RERUN, FETCH)
+SPANS = (PLACE, SUBMIT, BIND, LOOKUP, DISPATCH, WAIT, RERUN, FETCH)
 
 
 def op_scope(name: str):

@@ -26,12 +26,19 @@ Two execution paths, one correctness story:
     re-runs conservatively in isolation, so a lying bound can never poison a
     neighbour.
 
-Topology awareness: the server carries a logical device width and a
-monotonically increasing ``topology_generation``.  Losing devices
-(:meth:`QueryServer.degrade`) bumps the generation — which is part of the
-executable cache key, so every template re-traces exactly ONCE per
-(template, generation), never per request — and re-prices the per-device
-footprint.  With an :class:`AdmissionGate` configured,
+Topology: ``QueryServer(db, devices=n)`` with n > 1 serves on a mesh of
+the first n chips JAX sees.  It hash-partitions the tables once (paper
+§4.3, ``backend.PARTITION_KEYS``) and places the partitions on the mesh,
+where they stay; each template compiles once into one SPMD program
+(``backend.spmd_program``) whose exchanges are collectives between the
+chips.  With n == 1 it compiles one-chip ``LocalContext`` programs over
+whole tables.  The server carries a monotonically increasing
+``topology_generation``.  Losing devices (:meth:`QueryServer.degrade`)
+rebuilds the mesh over the survivors and re-places the partitions, and
+bumps the generation — which is part of the executable cache key, so every
+template re-traces exactly ONCE per (template, generation), never per
+request — and the per-device footprint grows with the placed partitions.
+With an :class:`AdmissionGate` configured,
 :meth:`QueryServer.submit_guarded` returns structured outcomes instead of
 opaque errors: :class:`Served` (full-width topology), :class:`Degraded`
 (answered, but on a shrunken topology), or :class:`Shed` (declined or
@@ -51,6 +58,7 @@ from repro.core import backend as B
 from repro.core import planner
 from repro.core import relational as rel
 from repro.core import tracing
+from repro.core.compat import make_mesh
 from repro.core.table import Table, to_numpy
 from repro.core.wire import CorruptPayload
 from .cache import PlanCache
@@ -60,8 +68,14 @@ __all__ = ["QueryServer", "BatchExecutor", "AdmissionGate",
            "Served", "Degraded", "Shed"]
 
 _PDTYPE = {"int64": jnp.int64, "float64": jnp.float64}
+_AXIS = "data"                 # the mesh axis the tables are partitioned on
 # capacity escalation of the conservative rerun after an overflow
 _RERUN_FACTOR = 4.0
+
+
+def _any(flag) -> bool:
+    """A program's overflow or corrupt flag: one per chip on a mesh."""
+    return bool(np.any(np.asarray(flag)))
 
 
 def _as_table(out):
@@ -147,9 +161,9 @@ class QueryServer:
         self._requests = 0           # submit's sequence number
         # join indexes each exact program builds, by method (trace time)
         self._index_builds: dict[tuple, dict[str, int]] = {}
+        # exchanges each mesh program makes, by kind, and bytes (trace time)
+        self._exchanges: dict[tuple, dict[str, int]] = {}
         self._phases = tracing.Phases()
-        self._tables = B._np_db_to_tables(db)
-        # topology state: logical width this server answers on behalf of
         if devices < 1:
             raise ValueError(f"devices must be >= 1, got {devices}")
         self.boot_devices = int(devices)
@@ -158,41 +172,65 @@ class QueryServer:
         self.gate = gate
         self.shed_count = 0
         self.backlog: list[tuple[PlanTemplate, dict | None, bool | None]] = []
-        self._db_bytes = float(sum(
-            np.asarray(col).nbytes
-            for t in db.tables.values() for col in t.values()))
+        self._mesh = None              # None: one chip, whole tables
+        if devices == 1:
+            self._tables = B._np_db_to_tables(db)
+        else:
+            chips = jax.devices()
+            if len(chips) < devices:
+                raise ValueError(f"devices={devices}, but JAX sees "
+                                 f"{len(chips)}")
+            self._chips = chips[:devices]
+            self._place()
 
     # -- topology -----------------------------------------------------------
+    def _place(self) -> None:
+        """Partition the tables over the live chips and put them there."""
+        with self._phases.span(tracing.PLACE, devices=self.devices):
+            self._mesh = make_mesh((self.devices,), (_AXIS,),
+                                   devices=self._chips[:self.devices])
+            self._tables = None          # free the old placement first
+            sharded, _ = B.partition_database(self.db, self.devices)
+            self._tables = B.place_partitions(sharded, self._mesh, _AXIS)
+
+    def _resize(self, devices: int) -> int:
+        if devices != self.devices:
+            self.devices = int(devices)
+            self.topology_generation += 1
+            if self._mesh is not None:
+                self._place()
+        return self.topology_generation
+
     def degrade(self, devices: int) -> int:
-        """Shrink the logical topology to ``devices`` survivors.  Bumps the
-        topology generation — every template re-traces exactly once against
-        the new generation (the generation is in the executable cache key).
+        """Shrink the topology to its first ``devices`` chips: on a mesh,
+        the partitions are re-placed over the survivors.  Bumps the topology
+        generation — every template re-traces exactly once against the new
+        generation (the generation is in the executable cache key).
         Returns the new generation."""
         if not 1 <= devices <= self.devices:
             raise ValueError(
                 f"degrade to {devices} from {self.devices} devices")
-        if devices != self.devices:
-            self.devices = int(devices)
-            self.topology_generation += 1
-        return self.topology_generation
+        return self._resize(devices)
 
     def restore(self, devices: int | None = None) -> int:
         """Recovered capacity (default: back to boot width).  A new
         generation as well — the topology changed."""
         devices = self.boot_devices if devices is None else int(devices)
-        if devices < 1:
+        if not 1 <= devices <= self.boot_devices:
             raise ValueError(f"restore to {devices} devices")
-        if devices != self.devices:
-            self.devices = devices
-            self.topology_generation += 1
-        return self.topology_generation
+        return self._resize(devices)
 
     def footprint_bytes(self, factor: float | None = None) -> float:
-        """Estimated per-device footprint at the live width: the device's
-        database partition plus exchange/join working buffers, which the
-        engine sizes as ``capacity_factor`` x the partition."""
+        """Estimated footprint of the fullest chip: the bytes of the tables
+        placed on it plus exchange/join working buffers, which the engine
+        sizes as ``capacity_factor`` x those bytes."""
         factor = self.capacity_factor if factor is None else factor
-        return self._db_bytes / self.devices * (1.0 + float(factor))
+        per_chip: dict = {}
+        for a in jax.tree.leaves(self._tables):
+            for shard in a.addressable_shards:
+                per_chip[shard.device] = (per_chip.get(shard.device, 0)
+                                          + shard.data.nbytes)
+        return max(per_chip.values()) * (1.0 + float(factor))
 
     def _exe_key(self, template: PlanTemplate, infer: bool,
                  factor: float) -> tuple:
@@ -217,20 +255,35 @@ class QueryServer:
         (``{"direct": 3, "sorted": 1}``), as its last trace counted them;
         ``rerun=True`` reads the conservative rerun's program.  None where
         that program has not been traced."""
+        return self._index_builds.get(self._key_of(template, infer, rerun))
+
+    def _key_of(self, template: PlanTemplate | int, infer: bool | None,
+                rerun: bool) -> tuple:
         if isinstance(template, int):
             template = TEMPLATES[template]
         if infer is None:
             infer = planner.planner_default()
-        key = self._exe_key(template, infer and not rerun,
-                            self.capacity_factor *
-                            (_RERUN_FACTOR if rerun else 1.0))
-        return self._index_builds.get(key)
+        return self._exe_key(template, infer and not rerun,
+                             self.capacity_factor *
+                             (_RERUN_FACTOR if rerun else 1.0))
+
+    def exchanges(self, template: PlanTemplate | int,
+                  infer: bool | None = None,
+                  rerun: bool = False) -> dict[str, int] | None:
+        """Exchanges between chips that the template's mesh program makes,
+        by kind (``shuffle``, ``broadcast``, ``gather``, ``allreduce``), and
+        the ``bytes`` they ship from each chip, as its last trace counted
+        them.  None where that program has not been traced, and on one chip,
+        where nothing is exchanged."""
+        return self._exchanges.get(self._key_of(template, infer, rerun))
 
     def _compile(self, template: PlanTemplate, infer: bool, factor: float,
                  key: tuple):
         query = template.query
         # host-side, once per (template, db): domain-sound hints/wire bounds
         info = query.info(self.db) if infer else None
+        if self._mesh is not None:
+            return self._compile_spmd(query, info, factor, key)
 
         def run(tables, pvals):
             # trace-time side effect: every (re)trace of this executable is
@@ -246,10 +299,30 @@ class QueryServer:
 
         return jax.jit(run)
 
+    def _compile_spmd(self, query, info, factor: float, key: tuple):
+        """One SPMD program over the placed partitions: the parameters enter
+        replicated, as traced scalars."""
+        def run(ctx, pvals):
+            self.recompiles += 1
+            out = planner._Executor(ctx, info, params=pvals).run(query.plan)
+            self._index_builds[key] = dict(ctx.stats.index_builds)
+            st = ctx.stats
+            self._exchanges[key] = {
+                "shuffle": st.shuffles, "broadcast": st.broadcasts,
+                "gather": st.final_gathers, "allreduce": st.allreduces,
+                "bytes": sum(e.total_bytes for e in st.log)}
+            return out
+
+        return B.spmd_program(run, self.db, self._mesh, _AXIS,
+                              capacity_factor=factor,
+                              join_method=self.join_method,
+                              use_kernel=self.use_kernel,
+                              wire_format=self.wire_format)
+
     def compiled(self, template: PlanTemplate | int,
                  infer: bool | None = None) -> jax.stages.Compiled:
         """The executable ``submit`` runs for ``template`` (exact serving),
-        as compiled for this server's device; compiles it if not yet served.
+        as compiled for this server's chips; compiles it if not yet served.
         """
         if isinstance(template, int):
             template = TEMPLATES[template]
@@ -315,7 +388,7 @@ class QueryServer:
         with span(tracing.DISPATCH):
             out, overflow, corrupt = fn(self._tables, pvals)
         with span(tracing.WAIT):
-            overflowed = bool(overflow)
+            overflowed = _any(overflow)
         if overflowed:
             # a domain-derived claim was too tight for this binding (or the
             # statistics lied): re-run conservatively — no hints, escalated
@@ -326,8 +399,8 @@ class QueryServer:
                 fn = self._executable(template, False,
                                       self.capacity_factor * _RERUN_FACTOR)
                 out, overflow, corrupt = fn(self._tables, pvals)
-                overflowed = bool(overflow)
-        if bool(corrupt):
+                overflowed = _any(overflow)
+        if _any(corrupt):
             raise CorruptPayload("serve: payload integrity check failed")
         if overflowed:
             raise RuntimeError(
@@ -335,7 +408,8 @@ class QueryServer:
                 f"rerun (capacity_factor="
                 f"{self.capacity_factor * _RERUN_FACTOR})")
         with span(tracing.FETCH):
-            return to_numpy(out)
+            # on a mesh every chip holds the whole answer: copy the first's
+            return to_numpy(out if self._mesh is None else B.first_shard(out))
 
     # -- approximate serving (repro.approx) --------------------------------
     def _approx_rewrite(self, template: PlanTemplate, den: int):

@@ -156,7 +156,8 @@ def test_direct_join_compiles_for_v5e(one_chip):
 
 
 def test_q6_spmd_program_compiles_for_v5e_2x2(topo, compiled_kernels):
-    """The four-chip path: run_distributed's program over a 2x2 mesh, with
+    """The four-chip path: an SPMD program (``backend.spmd_program``, the
+    server's and run_distributed's) over a 2x2 mesh, with
     ~2^20 lineitem rows per chip, holds its all-reduces and its kernel."""
     mesh = Mesh(np.array(topo.devices), ("data",))
     db = tpch.generate(0.7, seed=0)
@@ -166,6 +167,7 @@ def test_q6_spmd_program_compiles_for_v5e_2x2(topo, compiled_kernels):
     shapes = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=spec),
         sharded)
-    fn, _ = B.distributed_program(QUERIES[6], db, mesh, use_kernel=True)
-    text = fn.lower(shapes).compile().as_text()
+    fn = B.spmd_program(lambda ctx, _: QUERIES[6](ctx), db, mesh,
+                        use_kernel=True)
+    text = fn.lower(shapes, {}).compile().as_text()
     assert "all-reduce" in text and "tpu_custom_call" in text
