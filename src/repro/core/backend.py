@@ -71,6 +71,8 @@ class PlanStats:
     log: list = dataclasses.field(default_factory=list)
     # join indexes built, by the method each took: direct / sorted / hash
     index_builds: dict = dataclasses.field(default_factory=dict)
+    # group-by segment reductions, by segsum path: mxu / masked / scatter
+    agg_reductions: dict = dataclasses.field(default_factory=dict)
 
     def counts(self):
         return {"shuffles": self.shuffles, "broadcasts": self.broadcasts,
@@ -469,6 +471,7 @@ class LocalContext(_BaseContext):
                                       groups_hint=groups_hint,
                                       hash_factor=self.capacity_factor,
                                       use_kernel=self.use_kernel,
+                                      reductions=self.stats.agg_reductions,
                                       return_overflow=True)
         self.overflow = self.overflow | ov
         if groups_hint is not None:
@@ -492,7 +495,8 @@ class LocalContext(_BaseContext):
         self._count("allreduce")
         aggs, avg_post = _expand_avg(list(aggs))
         g = rel.group_aggregate(t, [], _eval_aggs(self, t, aggs),
-                                use_kernel=self.use_kernel)
+                                use_kernel=self.use_kernel,
+                                reductions=self.stats.agg_reductions)
         out = {name: g[name][0] for name in g.names}
         for name in avg_post:
             out[name] = out[f"__{name}_s"] / jnp.maximum(out[f"__{name}_c"], 1)
@@ -604,6 +608,7 @@ class DistContext(LocalContext):
                                           groups_hint=groups_hint,
                                           hash_factor=self.capacity_factor,
                                           use_kernel=self.use_kernel,
+                                          reductions=self.stats.agg_reductions,
                                           return_overflow=True)
         self.overflow = self.overflow | ov
         if groups_hint is not None:
@@ -650,6 +655,7 @@ class DistContext(LocalContext):
                                           groups_hint=groups_hint,
                                           hash_factor=self.capacity_factor,
                                           use_kernel=self.use_kernel,
+                                          reductions=self.stats.agg_reductions,
                                           return_overflow=True)
             self.overflow = self.overflow | ov
         for name in avg_post:
@@ -663,7 +669,8 @@ class DistContext(LocalContext):
         self._count("allreduce")        # corrupt faults here are simulated
         aggs, avg_post = _expand_avg(list(aggs))
         g = rel.group_aggregate(t, [], _eval_aggs(self, t, aggs),
-                                use_kernel=self.use_kernel)
+                                use_kernel=self.use_kernel,
+                                reductions=self.stats.agg_reductions)
         partials = {name: g[name][0] for name in g.names}
         ops = {name: _MERGE[op] for name, op, _ in aggs}
         out = ex.partial_to_global(partials, ops, self.axis)

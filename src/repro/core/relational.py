@@ -454,7 +454,8 @@ def group_aggregate(t: Table, key_cols: Sequence[str],
                     method: str = "auto", use_kernel: bool | None = None,
                     return_overflow: bool = False,
                     groups_hint: int | None = None,
-                    hash_factor: float = 2.0):
+                    hash_factor: float = 2.0,
+                    reductions: dict[str, int] | None = None):
     """Grouped aggregation; sortless when the key domain is provably small
     OR a distinct-group bound is claimed.
 
@@ -496,6 +497,11 @@ def group_aggregate(t: Table, key_cols: Sequence[str],
     is ascending packed key on all paths; capacity preserved
     (n_groups <= count <= capacity); output is compact.
 
+    ``reductions``, where given, counts at trace time the segment reductions
+    of the sortless paths by ``segsum`` path (``mxu`` / ``masked`` /
+    ``scatter``): one per aggregate column, one for the shared count.  The
+    sort path adds nothing.
+
     Rows past ``count`` are unspecified and differ between paths: notably a
     scalar min/max over ZERO valid rows leaves 0 at slot 0 on the direct
     path (matching the NumPy oracle's empty convention) but the reduction
@@ -514,14 +520,15 @@ def group_aggregate(t: Table, key_cols: Sequence[str],
             raise ValueError("group_aggregate: direct path needs key_bits "
                              f"with sum <= {DIRECT_AGG_BITS_MAX}")
         out, overflow = _group_aggregate_direct(t, key_cols, aggs, key_bits,
-                                                use_kernel)
+                                                use_kernel, reductions)
     elif method == "hash":
         if not hash_ok:
             raise ValueError("group_aggregate: hash path needs 1-2 key "
                              "columns and groups_hint <= "
                              f"{HASH_AGG_GROUPS_MAX}")
         out, overflow = _group_aggregate_hash(t, key_cols, aggs, groups_hint,
-                                              hash_factor, use_kernel)
+                                              hash_factor, use_kernel,
+                                              reductions)
     elif method == "sort":
         out = _group_aggregate_sorted(t, key_cols, aggs, key_bits)
         overflow = jnp.asarray(False)
@@ -530,11 +537,25 @@ def group_aggregate(t: Table, key_cols: Sequence[str],
     return (out, overflow) if return_overflow else out
 
 
+def _segment_reduce(gid: jax.Array, values: jax.Array | None, dom: int,
+                    op: str, use_kernel: bool,
+                    reductions: dict[str, int] | None) -> jax.Array:
+    """``segsum`` reduction, counted in ``reductions`` by its path, one per
+    column."""
+    if reductions is not None:
+        path, n = _ss_ops.reduction_count(gid, values, dom, op, use_kernel)
+        reductions[path] = reductions.get(path, 0) + n
+    return _ss_ops.segment_reduce(gid, values, dom, op=op,
+                                  use_kernel=use_kernel)
+
+
 def _reduce_aggs(t: Table, aggs, gid: jax.Array, dom: int, in_dom: jax.Array,
-                 cnt: jax.Array, use_kernel: bool, cap: int
+                 cnt: jax.Array, use_kernel: bool, cap: int,
+                 reductions: dict[str, int] | None
                  ) -> dict[str, jax.Array]:
     """Shared sortless reduction core (direct + hash paths): per-agg (dom,)
-    arrays via the segsum kernel, with same-dtype sums batched into one
+    arrays via ``segsum`` (its kernels, masked reductions or a scatter:
+    ``reduction_path``), with same-dtype sums batched into one
     multi-column call.  ``in_dom`` masks rows excluded from every aggregate
     (invalid, out-of-claimed-domain, unresolved); ``cnt`` is the group
     occupancy, which doubles as every count aggregate."""
@@ -550,18 +571,18 @@ def _reduce_aggs(t: Table, aggs, gid: jax.Array, dom: int, in_dom: jax.Array,
             sum_batches.setdefault(jnp.dtype(v.dtype), []).append((out_name, v))
         elif op == "min":
             v = jnp.where(in_dom, v, _dtype_max(v.dtype))
-            reduced[out_name] = _ss_ops.segment_reduce(
-                gid, v, dom, op="min", use_kernel=use_kernel)
+            reduced[out_name] = _segment_reduce(gid, v, dom, "min",
+                                                use_kernel, reductions)
         elif op == "max":
             v = jnp.where(in_dom, v, _dtype_min(v.dtype))
-            reduced[out_name] = _ss_ops.segment_reduce(
-                gid, v, dom, op="max", use_kernel=use_kernel)
+            reduced[out_name] = _segment_reduce(gid, v, dom, "max",
+                                                use_kernel, reductions)
         else:
             raise ValueError(f"unknown agg op {op!r}")
     for dt, items in sum_batches.items():
         stacked = jnp.stack([v for _, v in items], axis=1)
-        sums = _ss_ops.segment_reduce(gid, stacked, dom, op="sum",
-                                      use_kernel=use_kernel)
+        sums = _segment_reduce(gid, stacked, dom, "sum", use_kernel,
+                               reductions)
         for i, (name, _) in enumerate(items):
             reduced[name] = sums[:, i]
     return reduced
@@ -569,7 +590,8 @@ def _reduce_aggs(t: Table, aggs, gid: jax.Array, dom: int, in_dom: jax.Array,
 
 def _group_aggregate_direct(t: Table, key_cols: Sequence[str], aggs,
                             key_bits: Sequence[int] | None,
-                            use_kernel: bool) -> tuple[Table, jax.Array]:
+                            use_kernel: bool, reductions: dict[str, int] | None
+                            ) -> tuple[Table, jax.Array]:
     """Sortless path: dense gid = packed key; segsum kernel; cumsum compact."""
     cap = t.capacity
     valid = t.valid_mask()
@@ -593,8 +615,8 @@ def _group_aggregate_direct(t: Table, key_cols: Sequence[str], aggs,
     gid = jnp.where(in_dom, key, dom).astype(jnp.int32)   # dead slot = dom
 
     # group occupancy doubles as every count aggregate
-    cnt = _ss_ops.segment_reduce(gid, None, dom, op="count",
-                                 use_kernel=use_kernel)               # (dom,)
+    cnt = _segment_reduce(gid, None, dom, "count", use_kernel,
+                          reductions)                                 # (dom,)
     nonempty = cnt > 0
     ngroups = nonempty.sum().astype(jnp.int32)
     # compact dense slots to the front WITHOUT a sort: cumsum rank preserves
@@ -613,7 +635,8 @@ def _group_aggregate_direct(t: Table, key_cols: Sequence[str], aggs,
         dom_keys = (jnp.arange(dom, dtype=_I64) >> shift) & ((1 << b) - 1)
         out[k] = _scatter(dom_keys.astype(t[k].dtype))
 
-    reduced = _reduce_aggs(t, aggs, gid, dom, in_dom, cnt, use_kernel, cap)
+    reduced = _reduce_aggs(t, aggs, gid, dom, in_dom, cnt, use_kernel, cap,
+                           reductions)
     for out_name, _, _ in aggs:
         out[out_name] = _scatter(reduced[out_name])
     return Table(out, ngroups), overflow
@@ -621,7 +644,8 @@ def _group_aggregate_direct(t: Table, key_cols: Sequence[str], aggs,
 
 def _group_aggregate_hash(t: Table, key_cols: Sequence[str], aggs,
                           groups_hint: int, hash_factor: float,
-                          use_kernel: bool) -> tuple[Table, jax.Array]:
+                          use_kernel: bool, reductions: dict[str, int] | None
+                          ) -> tuple[Table, jax.Array]:
     """Hash-compaction path: trace-time dictionary -> ascending-key dense gid
     -> segsum kernel.  Zero sorts without provable key widths.
 
@@ -664,9 +688,9 @@ def _group_aggregate_hash(t: Table, key_cols: Sequence[str], aggs,
     for k in key_cols:
         out[k] = jnp.zeros((cap,), t[k].dtype).at[gid_drop].set(
             t[k], mode="drop")
-    cnt = _ss_ops.segment_reduce(gid, None, dcap, op="count",
-                                 use_kernel=use_kernel)
-    reduced = _reduce_aggs(t, aggs, gid, dcap, resolved, cnt, use_kernel, cap)
+    cnt = _segment_reduce(gid, None, dcap, "count", use_kernel, reductions)
+    reduced = _reduce_aggs(t, aggs, gid, dcap, resolved, cnt, use_kernel, cap,
+                           reductions)
     for out_name, _, _ in aggs:
         out[out_name] = _fit(reduced[out_name])
     return Table(out, ngroups), overflow

@@ -16,6 +16,25 @@ boundary case entirely; see tests/test_aggregate_paths.py.)
 
 Out-of-range gids (negative or > groups) are rerouted to the dead slot before
 the kernel runs, so garbage ids can never scribble into a real group.
+
+Paths
+-----
+:func:`reduction_path` names the way a reduction runs, from shapes and dtypes
+alone:
+
+* ``"mxu"`` -- the Pallas kernels: counts and float32 sums as a one-hot MXU
+  matmul, float32 min/max as a one-hot masked lane reduce (float64 too, in
+  interpret mode).
+* ``"masked"`` -- what the kernels cannot hold (integers, float64 on the
+  chip, counts past float32's exact range) while ``groups x columns`` is at
+  most :data:`MASKED_REDUCE_MAX`: each output slot and column is one
+  full-length reduction over the rows, ``reduce(where(gid == g, v, ident))``,
+  so the rows stream at memory speed and no ``(rows, groups)`` operand is
+  built.  The dead slot is simply not computed.
+* ``"scatter"`` -- the same dtypes over larger domains: one scatter-add (or
+  scatter-min/max) of every row, :func:`.ref.segment_reduce_ref`.  On the
+  TPU, updates that collide on a slot serialise, so this path costs about
+  100 ns a row when a handful of slots take every row.
 """
 from __future__ import annotations
 
@@ -36,6 +55,14 @@ _GROUP_BLOCK = 1024
 _TILE_MAX = 1 << 19
 # one-hot f32 count matmuls are exact while the row count fits the mantissa
 _F32_EXACT_ROWS = 1 << 24
+# the largest groups x columns a fallback reduction computes as masked
+# full-length reductions rather than one scatter of every row.  Each slot and
+# column is a pass and a fusion of its own, so the bound keeps compile time
+# small and the passes far under the scatter's time.  Timed on one v5e, 6 M
+# float64 rows: 6.5 ms at 8 x 7 against the scatter's 568 ms, compiled in
+# 1.8 s; 128 x 1 compiled in 15.8 s.  Compiled for a described v5e on a CPU
+# host (not run on the chip): 1 024 slots took 183 s.  PERF.md, Findings
+MASKED_REDUCE_MAX = 64
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -99,6 +126,63 @@ def _kernel_dtype_ok(dt, interpret: bool) -> bool:
     return dt == jnp.float32 or (dt == jnp.float64 and interpret)
 
 
+def _identity(op: str, dt):
+    """The reduction's identity in ``dt``: what an empty group reads."""
+    if op == "sum":
+        return jnp.zeros((), dt)
+    if jnp.issubdtype(dt, jnp.floating):
+        return jnp.asarray(jnp.inf if op == "min" else -jnp.inf, dt)
+    info = jnp.iinfo(dt)
+    return jnp.asarray(info.max if op == "min" else info.min, dt)
+
+
+def _masked_reduce(gids: jax.Array, values: jax.Array, groups: int,
+                   op: str) -> jax.Array:
+    """values (n, C) -> (groups, C): a static loop of 1-D reductions over the
+    rows, one per slot and column, each keeping the rows whose gid is the
+    slot.  Columns are sliced out whole, so the rows stay in the lanes."""
+    ident = _identity(op, values.dtype)
+    red = {"sum": partial(jnp.sum, dtype=values.dtype), "min": jnp.min,
+           "max": jnp.max}[op]
+    cols = []
+    for c in range(values.shape[1]):
+        col = values[:, c]
+        cols.append(jnp.stack([red(jnp.where(gids == g, col, ident))
+                               for g in range(groups)]))
+    return jnp.stack(cols, axis=1)
+
+
+def _columns(values: jax.Array | None) -> int:
+    return 1 if values is None or values.ndim == 1 else values.shape[1]
+
+
+def reduction_path(gids: jax.Array, values: jax.Array | None, groups: int,
+                   op: str = "sum", interpret: bool | None = None,
+                   use_kernel: bool = True) -> str:
+    """How :func:`segment_reduce` computes these arguments: ``"mxu"``,
+    ``"masked"`` or ``"scatter"`` (module docstring).  Static: it reads
+    shapes and dtypes only, so a caller can count paths at trace time."""
+    if interpret is None:
+        interpret = auto_interpret()
+    if use_kernel:
+        if op == "count":
+            if gids.shape[0] < _F32_EXACT_ROWS:
+                return "mxu"
+        elif jnp.issubdtype(values.dtype, jnp.floating) and \
+                _kernel_dtype_ok(values.dtype, interpret):
+            return "mxu"
+    return "masked" if groups * _columns(values) <= MASKED_REDUCE_MAX \
+        else "scatter"
+
+
+def reduction_count(gids: jax.Array, values: jax.Array | None, groups: int,
+                    op: str = "sum", use_kernel: bool = True) -> tuple[str, int]:
+    """:func:`reduction_path` of these arguments, and the reductions it
+    stands for: one per column of ``values`` (a count is one)."""
+    return (reduction_path(gids, values, groups, op, use_kernel=use_kernel),
+            _columns(values))
+
+
 @partial(jax.jit, static_argnames=("groups", "op", "blk", "interpret",
                                    "use_kernel"))
 def segment_reduce(gids: jax.Array, values: jax.Array | None, groups: int,
@@ -107,42 +191,39 @@ def segment_reduce(gids: jax.Array, values: jax.Array | None, groups: int,
                    use_kernel: bool = True) -> jax.Array:
     """Sortless grouped reduction: sum / count / min / max, dtype-preserving.
 
-    The TPU fast path is the one-hot MXU matmul (sum/count) or the one-hot
-    masked lane reduce (min/max); dtypes the hardware kernels cannot hold
-    exactly (integers, float64 outside interpret mode) fall back to jnp
-    scatter-reduce — still sortless, still dead-slot routed.  ``op="count"``
+    The path is :func:`reduction_path`'s.  The TPU fast path is the one-hot
+    MXU matmul (sum/count) or the one-hot masked lane reduce (min/max).
+    Dtypes the hardware kernels cannot hold exactly (integers, float64
+    outside interpret mode) fall back, still sortless and dead-slot routed:
+    to masked full-length reductions while ``groups x columns`` is at most
+    :data:`MASKED_REDUCE_MAX`, else to a jnp scatter-reduce.  ``op="count"``
     ignores ``values`` and returns int64 row counts per group.
     ``interpret=None`` auto-selects: compiled on TPU, interpret elsewhere.
     """
+    if op not in ("sum", "count", "min", "max"):
+        raise ValueError(f"unknown segment reduce op {op!r}")
     if interpret is None:
         interpret = auto_interpret()
+    path = reduction_path(gids, values, groups, op, interpret, use_kernel)
     if op == "count":
         n = gids.shape[0]
-        if use_kernel and n < _F32_EXACT_ROWS:
+        if path == "mxu":
             out = _sum_kernel(gids, jnp.ones((n, 1), jnp.float32), groups,
                               blk, interpret)[:, 0]
             return jnp.round(out).astype(jnp.int64)
-        return segment_reduce_ref(_route_dead(gids, groups),
-                                  jnp.ones((n,), jnp.int64), groups, "sum")
-    if op not in ("sum", "min", "max"):
-        raise ValueError(f"unknown segment reduce op {op!r}")
+        op, values = "sum", jnp.ones((n,), jnp.int64)
     squeeze = values.ndim == 1
     v = values[:, None] if squeeze else values
-    kernel_ok = use_kernel and jnp.issubdtype(v.dtype, jnp.floating) and \
-        _kernel_dtype_ok(v.dtype, interpret)
-    if op == "sum":
-        if kernel_ok:
+    if path == "mxu":
+        if op == "sum":
             out = _sum_kernel(gids, v, groups, blk, interpret)
         else:
-            out = segment_reduce_ref(_route_dead(gids, groups), v, groups,
-                                     "sum")
-    else:
-        if kernel_ok:
             cols = [_minmax_kernel(gids, v[:, i], groups, op, blk, interpret)
                     for i in range(v.shape[1])]
             out = jnp.stack(cols, axis=1)
-        else:
-            out = segment_reduce_ref(_route_dead(gids, groups), v, groups, op)
+    else:
+        reduce = _masked_reduce if path == "masked" else segment_reduce_ref
+        out = reduce(_route_dead(gids, groups), v, groups, op)
     return out[:, 0] if squeeze else out
 
 

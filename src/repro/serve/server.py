@@ -161,6 +161,9 @@ class QueryServer:
         self._requests = 0           # submit's sequence number
         # join indexes each exact program builds, by method (trace time)
         self._index_builds: dict[tuple, dict[str, int]] = {}
+        # group-by segment reductions each exact program makes, by segsum
+        # path (trace time)
+        self._agg_reductions: dict[tuple, dict[str, int]] = {}
         # exchanges each mesh program makes, by kind, and bytes (trace time)
         self._exchanges: dict[tuple, dict[str, int]] = {}
         self._phases = tracing.Phases()
@@ -257,6 +260,16 @@ class QueryServer:
         that program has not been traced."""
         return self._index_builds.get(self._key_of(template, infer, rerun))
 
+    def agg_reductions(self, template: PlanTemplate | int,
+                       infer: bool | None = None,
+                       rerun: bool = False) -> dict[str, int] | None:
+        """Group-by segment reductions the template's exact program makes,
+        by ``segsum`` path (``{"mxu": 1, "masked": 7}``; ``mxu`` / ``masked``
+        / ``scatter``), as its last trace counted them; sort-path group-bys
+        count none.  ``rerun=True`` reads the conservative rerun's program.
+        None where that program has not been traced."""
+        return self._agg_reductions.get(self._key_of(template, infer, rerun))
+
     def _key_of(self, template: PlanTemplate | int, infer: bool | None,
                 rerun: bool) -> tuple:
         if isinstance(template, int):
@@ -295,6 +308,7 @@ class QueryServer:
                                  wire_format=self.wire_format)
             out = planner._Executor(ctx, info, params=pvals).run(query.plan)
             self._index_builds[key] = dict(ctx.stats.index_builds)
+            self._agg_reductions[key] = dict(ctx.stats.agg_reductions)
             return _as_table(out), ctx.overflow, ctx.corrupt
 
         return jax.jit(run)
@@ -306,6 +320,7 @@ class QueryServer:
             self.recompiles += 1
             out = planner._Executor(ctx, info, params=pvals).run(query.plan)
             self._index_builds[key] = dict(ctx.stats.index_builds)
+            self._agg_reductions[key] = dict(ctx.stats.agg_reductions)
             st = ctx.stats
             self._exchanges[key] = {
                 "shuffle": st.shuffles, "broadcast": st.broadcasts,

@@ -19,6 +19,7 @@ from repro.core import relational as R
 from repro.core.table import from_numpy, to_numpy
 from repro.data import tpch
 from repro.kernels.segsum import ops as ss
+from repro.kernels.segsum.ref import segment_reduce_ref
 
 OPS4 = [("s", "sum", "v"), ("c", "count", None),
         ("mn", "min", "v"), ("mx", "max", "v")]
@@ -405,3 +406,117 @@ def test_hinted_queries_kernel_vs_oracle_paths(qid):
         np.testing.assert_allclose(np.asarray(r_k[k], np.float64),
                                    np.asarray(r_ref[k], np.float64),
                                    rtol=1e-7, err_msg=f"q{qid} {k} vs oracle")
+
+
+# ---------------------------------------------------------------------------
+# the kernels' fallback: masked reductions over small domains, else scatter
+# ---------------------------------------------------------------------------
+
+_MAX = ss.MASKED_REDUCE_MAX
+
+
+def _fallback_case(op, dtype, groups, seed, n=700, all_invalid=False):
+    """gids with negatives, the dead slot and ids past it, and the last of
+    several slots left empty; values of ``dtype`` (None for count)."""
+    rng = np.random.default_rng(seed)
+    if all_invalid:
+        gids = rng.choice([-7, -1, groups, groups + 1, groups + 40], n)
+    else:
+        gids = rng.integers(-3, groups + 4, n)
+        if groups > 1:
+            gids[gids == groups - 1] = -2
+        gids[:4] = [-1, groups, groups + 5, 0]
+    gids = jnp.asarray(gids.astype(np.int32))
+    if op == "count":
+        return gids, None
+    if dtype == "float64":
+        return gids, jnp.asarray(rng.uniform(0.0, 1000.0, n))
+    return gids, jnp.asarray(rng.integers(-10**6, 10**6, n).astype(dtype))
+
+
+def _check_fallback(op, gids, vals, groups):
+    got = np.asarray(ss.segment_reduce(gids, vals, groups, op=op,
+                                       use_kernel=False))
+    if op == "count":
+        want = segment_reduce_ref(gids, jnp.ones(gids.shape, jnp.int64),
+                                     groups, "sum")
+    else:
+        want = segment_reduce_ref(gids, vals, groups, op)
+    want = np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == (groups,)
+    if op == "sum" and got.dtype.kind == "f":
+        np.testing.assert_allclose(got, want, rtol=1e-12, atol=0)
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("groups", [1, 8, 33, _MAX, _MAX + 1])
+@pytest.mark.parametrize("dtype", ["float64", "int64", "int32"])
+@pytest.mark.parametrize("op", ["sum", "count", "min", "max"])
+def test_fallback_matches_scatter_ref(op, dtype, groups):
+    """Up to MASKED_REDUCE_MAX slots the fallback is the masked path, one
+    past it the scatter; both agree with the scatter oracle: ints and
+    min/max exactly, float sums within 1e-12, empty groups at the
+    identity, and out-of-range ids in no group."""
+    gids, vals = _fallback_case(op, dtype, groups, seed=groups)
+    assert ss.reduction_path(gids, vals, groups, op, use_kernel=False) == \
+        ("masked" if groups <= _MAX else "scatter")
+    _check_fallback(op, gids, vals, groups)
+
+
+@pytest.mark.parametrize("groups", [1, 33])
+@pytest.mark.parametrize("dtype", ["float64", "int64", "int32"])
+@pytest.mark.parametrize("op", ["sum", "count", "min", "max"])
+def test_fallback_all_rows_invalid(op, dtype, groups):
+    """Every id negative, the dead slot or past it: every slot reads the
+    reduction's identity, as the oracle's does."""
+    gids, vals = _fallback_case(op, dtype, groups, seed=7, all_invalid=True)
+    _check_fallback(op, gids, vals, groups)
+
+
+@pytest.mark.parametrize("op,dtype,groups,cols,rows,interpret,path", [
+    ("count", None, 32, 1, 6_000_000, False, "mxu"),
+    ("count", None, 32, 1, 1 << 24, False, "masked"),
+    ("sum", "float32", 8, 7, 6_000_000, False, "mxu"),
+    ("min", "float32", 8, 1, 6_000_000, False, "mxu"),
+    ("sum", "float64", 8, 7, 6_000_000, True, "mxu"),
+    ("sum", "float64", 8, 7, 6_000_000, False, "masked"),
+    ("sum", "int64", 8, 2, 6_000_000, False, "masked"),
+    ("max", "int32", 8, 1, 6_000_000, False, "masked"),
+    ("sum", "float64", _MAX // 7, 7, 6_000_000, False, "masked"),
+    ("sum", "float64", _MAX // 7 + 1, 7, 6_000_000, False, "scatter"),
+    ("sum", "float64", _MAX + 1, 1, 6_000_000, False, "scatter"),
+])
+def test_reduction_path_by_shape_and_dtype(op, dtype, groups, cols, rows,
+                                           interpret, path):
+    """The path a compiled (not interpreted) TPU program takes, from shapes
+    and dtypes alone: counts and float32 on the kernels, float64 and ints
+    masked while groups x columns <= MASKED_REDUCE_MAX, then scattered."""
+    import jax
+    gids = jax.ShapeDtypeStruct((rows,), jnp.int32)
+    vals = None if dtype is None else \
+        jax.ShapeDtypeStruct((rows, cols), jnp.dtype(dtype))
+    assert ss.reduction_path(gids, vals, groups, op, interpret=interpret,
+                             use_kernel=True) == path
+
+
+@pytest.fixture(scope="module")
+def small_server():
+    from repro.serve.server import QueryServer
+    return QueryServer(tpch.generate(0.01, seed=3))
+
+
+@pytest.mark.parametrize("qid,masked", [(1, True), (5, True), (6, True),
+                                        (12, True), (14, True), (19, True),
+                                        (3, False), (18, False)])
+def test_agg_reductions_by_template(small_server, qid, masked):
+    """The served programs count their group-by reductions by path: the
+    direct-path queries reduce every aggregate masked (no scatter); Q3's and
+    Q18's group-bys take the sort path and count none masked."""
+    assert small_server.agg_reductions(qid) is None      # not traced yet
+    small_server.submit(qid)
+    red = small_server.agg_reductions(qid)
+    if masked:
+        assert red.get("masked", 0) > 0 and "scatter" not in red, red
+    else:
+        assert "masked" not in red, red
